@@ -413,4 +413,6 @@ def main(tuned_recs=None, measured_rec=None, skip_rec=None, decode_rec=None,
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("\n".join(main()))

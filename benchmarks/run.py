@@ -80,6 +80,8 @@ def main(argv=None) -> None:
                     help="small wall-clocked shapes for the CI smoke job "
                          "(full schema, reduced measurement cost)")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     # The report must reflect the code under benchmark, not whatever an
     # earlier run left in the user-global autotune cache — tune fresh in a

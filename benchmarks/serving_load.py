@@ -194,11 +194,12 @@ def run_mix(cfg, name: str, spec: dict, *, smoke: bool = False,
     overrides the physical pool size (0 = the spec's own ``pool_pages``
     key, falling back to contiguous-equivalent) — the paging comparison
     uses it to pin both paths to the same KV-memory budget."""
+    import jax
     import jax.numpy as jnp
 
     from repro.kernels import autotune
     from repro.launch import serve, specs
-    from repro.launch.mesh import make_host_mesh, set_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.scheduler import Scheduler
     from repro.parallel import sharding as shd
     from repro.runtime import fault_tolerance, loadgen, paging
@@ -265,7 +266,7 @@ def run_mix(cfg, name: str, spec: dict, *, smoke: bool = False,
     sched = spec.get("sched", "fcfs")
 
     mesh = make_host_mesh(data=1, model=1)
-    with set_mesh(mesh), shd.use_rules(specs.rules_for(mesh)):
+    with jax.set_mesh(mesh), shd.use_rules(specs.rules_for(mesh)):
         server = serve.Server(cfg, batch, max_len, prefill_len=prefill_len,
                               slot_lengths=dist, paged=paged_spec,
                               kv_dtype=kv_dtype)
@@ -541,6 +542,8 @@ def main(argv=None) -> int:
                     help="also write each mix's trace as DIR/<mix>.jsonl "
                          "(replayable via launch.serve --load-trace)")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     # Tune fresh in a throwaway cache unless the caller pinned one — the
     # report must reflect the code under benchmark (same rule as run.py).

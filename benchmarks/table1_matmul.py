@@ -182,4 +182,6 @@ def main(tuned_recs=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("\n".join(main()))

@@ -157,4 +157,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("\n".join(main()))

@@ -1,7 +1,10 @@
 """Architecture config registry: ``--arch <id>`` resolves here.
 
 Each module defines ``CONFIG`` (the exact published configuration) and
-``SMOKE`` (a reduced same-family config for CPU tests).
+``SMOKE`` (a reduced same-family config for CPU tests).  A module may
+also define a *cut* of ``CONFIG`` that one chip serves (published widths,
+depth cut, the cut written into the module); ``CUTS`` names each one for
+``--arch``.
 """
 
 from __future__ import annotations
@@ -21,7 +24,12 @@ ARCHS = (
     "internvl2_2b",
 )
 
-_ALIASES = {a.replace("_", "-"): a for a in ARCHS}
+# --arch name -> (module, attribute) of a one-chip cut.
+CUTS = {
+    "qwen3_14b_1chip": ("qwen3_14b", "ONE_CHIP"),
+}
+
+_ALIASES = {a.replace("_", "-"): a for a in (*ARCHS, *CUTS)}
 _ALIASES.update({
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b",
@@ -38,12 +46,17 @@ _ALIASES.update({
 
 def _module(arch: str):
     arch = _ALIASES.get(arch, arch)
+    arch = CUTS[arch][0] if arch in CUTS else arch
     if arch not in ARCHS:
-        raise KeyError(f"unknown arch {arch!r}; known: {sorted(_ALIASES)}")
+        raise KeyError(f"unknown arch {arch!r}; known: "
+                       f"{sorted(_ALIASES) + sorted(CUTS)}")
     return importlib.import_module(f"repro.configs.{arch}")
 
 
 def get(arch: str):
+    arch = _ALIASES.get(arch, arch)
+    if arch in CUTS:
+        return getattr(_module(arch), CUTS[arch][1])
     return _module(arch).CONFIG
 
 
@@ -53,3 +66,7 @@ def get_smoke(arch: str):
 
 def list_archs():
     return list(ARCHS)
+
+
+def list_cuts():
+    return list(CUTS)

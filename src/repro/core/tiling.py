@@ -28,10 +28,15 @@ TPU adaptation
 * the MXU is a 128x128 systolic array, so tiles must be multiples of 128 and
   ``z = 1`` would waste the contraction dimension entirely.  Q is independent
   of z, so we raise z to an MXU-friendly depth "for free" in traffic — but z
-  now occupies VMEM (A tile ``y*z``, double-buffered B tile ``2*z*x``, C
-  accumulator ``y*x``), giving the refined constraint
+  now occupies VMEM.  The constraint is the scoped VMEM the Pallas kernel
+  actually allocates (`Tile.kernel_vmem_bytes`): the pipeline
+  double-buffers the A tile ``y*z``, the B tile ``z*x`` and the output
+  tile ``y*x``, and the f32 accumulator ``y*x`` is scratch —
 
-      y*z + 2*z*x + x*y <= L.
+      2*(y*z + z*x)*b + 2*x*y*b_out + 4*x*y <= L_bytes
+
+  — the same budget each kernel asks the compiler for, so a tile the model
+  admits is one the compiler grants.
 
 * the broadcast of A across cores becomes A-tile *reuse across the grid's N
   axis* inside one chip (p = 1 in-kernel) and an all-gather of the stationary
@@ -59,13 +64,27 @@ class Tile:
     x: int  # cols of the C tile (N axis)
     z: int  # contraction tile (K axis)
 
-    def vmem_elems(self, double_buffer: bool = True) -> int:
-        db = 2 if double_buffer else 1
-        return self.y * self.z + db * self.z * self.x + self.y * self.x
+    def kernel_vmem_bytes(self, dtype_bytes: int, out_bytes: int | None = None,
+                          accum_bytes: int = 4, buffers: int = 2) -> int:
+        """Scoped VMEM of the blocked-matmul kernel on this tile: A, B and
+        output blocks, ``buffers``-deep in the Pallas pipeline, plus the
+        accumulator scratch."""
+        out_bytes = dtype_bytes if out_bytes is None else out_bytes
+        return (buffers * ((self.y * self.z + self.z * self.x) * dtype_bytes
+                           + self.y * self.x * out_bytes)
+                + self.y * self.x * accum_bytes)
 
     def as_block_shapes(self):
         """BlockSpec shapes for (A, B, C) of a y/x/z-tiled matmul."""
         return (self.y, self.z), (self.z, self.x), (self.y, self.x)
+
+
+def max_depth(y: int, x: int, budget: int, dtype_bytes: int,
+              accum_bytes: int = 4, buffers: int = 2) -> int:
+    """Deepest (unaligned) z whose `Tile.kernel_vmem_bytes` fits ``budget``
+    for a y x x output tile — that footprint solved for z."""
+    return (budget - y * x * (buffers * dtype_bytes + accum_bytes)) // max(
+        buffers * (y + x) * dtype_bytes, 1)
 
 
 def comm_volume(n: int, tile: Tile, p: int = 1) -> float:
@@ -148,8 +167,8 @@ def solve_tpu(
     db = 2 if double_buffer else 1
 
     def fits(y: int, x: int, z: int) -> bool:
-        used = (y * z + db * z * x) * dtype_bytes + y * x * accum_bytes
-        return used <= budget
+        return Tile(y, x, z).kernel_vmem_bytes(
+            dtype_bytes, accum_bytes=accum_bytes, buffers=db) <= budget
 
     # Analytical seed: treat L as budget in "effective elements".
     L_eff = budget // max(dtype_bytes, 1)
@@ -171,10 +190,8 @@ def solve_tpu(
         for x in _aligned_candidates(x_hi, align):
             # Largest aligned z that still fits — traffic is z-independent,
             # deeper z amortizes accumulator read/write and MXU pipelining.
-            z_max = (budget - y * x * accum_bytes) // max(
-                (y + db * x) * dtype_bytes, 1
-            )
-            z_max = clampdim(z_max, k)
+            z_max = clampdim(max_depth(y, x, budget, dtype_bytes,
+                                       accum_bytes, db), k)
             z = (z_max // align) * align
             if z < align:
                 continue

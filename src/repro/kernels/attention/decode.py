@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.core import hardware
+from repro.kernels.attention.kernel import NEG_INF, softmax_update
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -58,18 +59,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (g, block_k)
         k_pos = jj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, NEG_INF)
-
-        m_prev = m_ref[...]                              # (g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new <= NEG_INF, 0.0, p)          # fully-masked block
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        softmax_update(jnp.where(k_pos < length, s, NEG_INF), v,
+                       m_ref, l_ref, acc_ref)
 
     @pl.when(jj == k_steps - 1)
     def _store():
@@ -145,6 +136,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bkv, g, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
         interpret=interpret,
     )(lengths, q, k, v)
     return out.astype(out_dtype)
@@ -181,19 +175,41 @@ def gqa_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.reshape(b, hkv, g, dh).reshape(b, hq, dh)
 
 
+def _attend_rows(q, k, v, rows, k0, length, m_ref, l_ref, acc_ref, *,
+                 scale: float, k_scale=None, v_scale=None):
+    """One online-softmax update of the scratch ``rows`` (static slice of
+    the q-row axis) against one streamed K/V block starting at absolute
+    key position ``k0``.  ``k_scale``/``v_scale`` are the int8 layout's
+    per-row (block, 1) dequantization columns (None for a float cache)."""
+    if k_scale is not None:
+        k = k.astype(jnp.float32) * k_scale
+        v = v.astype(jnp.float32) * v_scale
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale      # (g, block)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    softmax_update(jnp.where(k_pos < length, s, NEG_INF), v,
+                   m_ref, l_ref, acc_ref, rows=rows)
+
+
 def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *,
-                         scale: float, page_size: int, max_pages: int):
+                         scale: float, page_size: int, max_pages: int,
+                         hkv: int):
     """Same online-softmax body as `_decode_kernel`, but the grid's k axis
     walks the slot's *page table* instead of a contiguous cache: grid step
     j streams physical page ``pt_ref[slot, j]`` (the index maps below do
     the translation; ``pt_ref`` itself is unused here but must ride the
-    scalar-prefetch signature)."""
+    scalar-prefetch signature).  One grid row is one slot: the page block
+    carries every KV head (a (1, page_size, Hkv, dh) block is the only
+    one Mosaic tiles, its last two dims being whole), and the kernel
+    selects head h for the q rows of its GQA group."""
     del pt_ref
     bb = pl.program_id(0)
     jj = pl.program_id(1)
     length = len_ref[bb]
     last = jnp.maximum(0, (length - 1) // page_size)
+    g = q_ref.shape[1] // hkv
 
     @pl.when(jj == 0)
     def _init():
@@ -203,26 +219,11 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(jj <= last)
     def _compute():
-        q = q_ref[0]                                     # (g, dh)
-        k = k_ref[0, :, 0]                               # (page_size, dh)
-        v = v_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (g, page_size)
-        k_pos = jj * page_size + jax.lax.broadcasted_iota(jnp.int32,
-                                                          s.shape, 1)
-        s = jnp.where(k_pos < length, s, NEG_INF)
-
-        m_prev = m_ref[...]                              # (g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new <= NEG_INF, 0.0, p)          # fully-masked page
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h in range(hkv):
+            rows = slice(h * g, (h + 1) * g)
+            _attend_rows(q_ref[0, rows, :], k_ref[0, :, h, :],
+                         v_ref[0, :, h, :], rows, jj * page_size, length,
+                         m_ref, l_ref, acc_ref, scale=scale)
 
     @pl.when(jj == max_pages - 1)
     def _store():
@@ -243,15 +244,14 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
 
     The page table rides the *second* scalar-prefetch argument next to
     the lengths vector: the K/V BlockSpec index maps read
-    ``pages[slot, min(j, last)]`` to pick the physical pool row each grid
+    ``pages[slot, min(j, last)]`` to pick the physical pool page each grid
     step streams, so a slot touches exactly its own pages — blocks past a
     slot's depth are neither streamed nor multiplied, same skip law as
     the contiguous kernel, and unassigned (-1) entries are never reached
-    because ``j`` is clamped to the slot's last valid page.  The GQA
-    group folds into the q-row axis per KV head exactly like
-    `gqa_decode_attention`; the pool is NOT folded (it has no batch
-    axis — that is the whole point), so the index maps carry the
-    row -> (slot, kv_head) split instead.
+    because ``j`` is clamped to the slot's last valid page.  Each grid
+    row is one slot and streams each of its pages once for all KV heads;
+    the GQA group of KV head h is q rows ``h*g:(h+1)*g`` (the pool has
+    no batch axis to fold — that is the whole point).
     """
     out_dtype = q.dtype
     if q.dtype != k_pool.dtype:
@@ -259,47 +259,47 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
     b, hq, dh = q.shape
     num_pages, page_size, hkv, _ = k_pool.shape
     max_pages = pages.shape[1]
-    g = hq // hkv
     if scale is None:
         scale = 1.0 / (dh ** 0.5)
     lengths = _row_lengths(length, b, max_pages * page_size)
-    lengths = jnp.repeat(lengths, hkv)              # row r -> slot r // hkv
     pt = jnp.asarray(pages, jnp.int32)
-    qf = q.reshape(b, hkv, g, dh).reshape(b * hkv, g, dh)
-    bkv = b * hkv
 
     def kv_index(r, j, len_ref, pt_ref):
         last = jnp.maximum(0, (len_ref[r] - 1) // page_size)
-        page = pt_ref[r // hkv, jnp.minimum(j, last)]
+        page = pt_ref[r, jnp.minimum(j, last)]
         # Clamp keeps even a pathological table in bounds; the length
         # mask already zeroes anything past the valid prefix.
-        return (jnp.clip(page, 0, num_pages - 1), 0, r % hkv, 0)
+        return (jnp.clip(page, 0, num_pages - 1), 0, 0, 0)
 
     fn = functools.partial(_paged_decode_kernel, scale=scale,
-                           page_size=page_size, max_pages=max_pages)
+                           page_size=page_size, max_pages=max_pages,
+                           hkv=hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bkv, max_pages),
+        grid=(b, max_pages),
         in_specs=[
-            pl.BlockSpec((1, g, dh), lambda r, j, len_ref, pt_ref: (r, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh), kv_index),
-            pl.BlockSpec((1, page_size, 1, dh), kv_index),
+            pl.BlockSpec((1, hq, dh), lambda r, j, len_ref, pt_ref: (r, 0, 0)),
+            pl.BlockSpec((1, page_size, hkv, dh), kv_index),
+            pl.BlockSpec((1, page_size, hkv, dh), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, g, dh),
+        out_specs=pl.BlockSpec((1, hq, dh),
                                lambda r, j, len_ref, pt_ref: (r, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         fn,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bkv, g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
         interpret=interpret,
-    )(lengths, pt, qf, k_pool, v_pool)
-    return out.reshape(b, hkv, g, dh).reshape(b, hq, dh).astype(out_dtype)
+    )(lengths, pt, q, k_pool, v_pool)
+    return out.astype(out_dtype)
 
 
 def paged_decode_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,

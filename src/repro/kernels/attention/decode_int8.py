@@ -3,14 +3,15 @@
 Same online-softmax / per-row valid-prefix-skip structure as
 `decode.py`, but the KV cache is stored and **streamed as int8** with
 one f32 scale per token row per KV head (`runtime/quantize.py`):
-each grid step fetches an int8 K/V block plus its (block_k,) scale
-vector, dequantizes **in register** (``q8.astype(f32) * scale[:, None]``
-— the Pallas int8 pattern: upcast once in VMEM, never in HBM), and
-accumulates in f32.  Streamed bytes per token per KV head drop from
-``2 * dh * itemsize`` to ``dh + 4`` for each of K and V — ~1.88x at
-dh = 64 — at a bounded accuracy cost (half a quantization step per
-element, see the quantize module; the `decode_int8` bench row gates
-both numbers in CI).
+each grid step fetches an int8 K/V block plus its block of scales,
+dequantizes **in register** (the Pallas int8 pattern: upcast once in
+VMEM, never in HBM; the contiguous kernel folds its lane-dense scale
+row into the logits and probabilities, the paged kernel multiplies each
+head's rows by their scale column), and accumulates in f32.  Streamed
+bytes per token per KV head drop from ``2 * dh * itemsize`` to
+``dh + 4`` for each of K and V — ~1.88x at dh = 64 — at a bounded
+accuracy cost (half a quantization step per element, see the quantize
+module; the `decode_int8` bench row gates both numbers in CI).
 
 Tokens are quantized once at cache-write time (`models/layers.py`
 scatter-on-write), so this kernel never quantizes — it only streams and
@@ -28,7 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.attention.decode import NEG_INF, _row_lengths, decode_ref
+from repro.core import hardware
+from repro.kernels.attention.decode import (_attend_rows, _row_lengths,
+                                            decode_ref)
+from repro.kernels.attention.kernel import NEG_INF, softmax_update
 from repro.runtime import quantize
 
 
@@ -49,25 +53,19 @@ def _quantized_decode_kernel(len_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
     @pl.when(jj <= last)
     def _compute():
         q = q_ref[0].astype(jnp.float32)                 # (g, dh)
-        # In-register dequant: int8 block * per-row f32 scale.
-        k = kq_ref[0].astype(jnp.float32) * ks_ref[0][:, None]
-        v = vq_ref[0].astype(jnp.float32) * vs_ref[0][:, None]
+        # In-register dequant, folded onto the block's key axis: the
+        # per-row scales arrive as a lane-dense (1, block_k) row, so K's
+        # scale multiplies the logits' columns and V's the probabilities'
+        # columns — (q . kq) * ks == q . (kq * ks), likewise for V.
+        ks = ks_ref[0]                                   # (1, block_k)
+        vs = vs_ref[0]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (g, block_k)
+            q, kq_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * (ks * scale)
         k_pos = jj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < length, s, NEG_INF)
-
-        m_prev = m_ref[...]                              # (g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new <= NEG_INF, 0.0, p)          # fully-masked block
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        softmax_update(jnp.where(k_pos < length, s, NEG_INF),
+                       vq_ref[0].astype(jnp.float32), m_ref, l_ref, acc_ref,
+                       p_scale=vs)
 
     @pl.when(jj == k_steps - 1)
     def _store():
@@ -106,7 +104,12 @@ def quantized_decode_attention(q: jax.Array, kq: jax.Array, ks: jax.Array,
 
     def scale_index(b, j, len_ref):
         last = jnp.maximum(0, (len_ref[b] - 1) // block_k)
-        return (b, jnp.minimum(j, last))
+        return (b, 0, jnp.minimum(j, last))
+
+    # (BKV, 1, L): the scale block's last two dims are then (1, block_k),
+    # which Mosaic tiles (a (1, block_k) block over (BKV, L) it refuses).
+    ks = ks[:, None, :]
+    vs = vs[:, None, :]
 
     fn = functools.partial(_quantized_decode_kernel, scale=scale,
                            block_k=block_k, k_steps=k_steps)
@@ -116,9 +119,9 @@ def quantized_decode_attention(q: jax.Array, kq: jax.Array, ks: jax.Array,
         in_specs=[
             pl.BlockSpec((1, g, dh), lambda b, j, len_ref: (b, 0, 0)),
             pl.BlockSpec((1, block_k, dh), kv_index),
-            pl.BlockSpec((1, block_k), scale_index),
+            pl.BlockSpec((1, 1, block_k), scale_index),
             pl.BlockSpec((1, block_k, dh), kv_index),
-            pl.BlockSpec((1, block_k), scale_index),
+            pl.BlockSpec((1, 1, block_k), scale_index),
         ],
         out_specs=pl.BlockSpec((1, g, dh), lambda b, j, len_ref: (b, 0, 0)),
         scratch_shapes=[
@@ -131,6 +134,9 @@ def quantized_decode_attention(q: jax.Array, kq: jax.Array, ks: jax.Array,
         fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bkv, g, dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
         interpret=interpret,
     )(lengths, q, kq, ks, vq, vs)
     return out.astype(out_dtype)
@@ -176,15 +182,18 @@ def _paged_quantized_decode_kernel(len_ref, pt_ref, q_ref, kq_ref, ks_ref,
                                    vq_ref, vs_ref, o_ref,
                                    m_ref, l_ref, acc_ref, *,
                                    scale: float, page_size: int,
-                                   max_pages: int):
+                                   max_pages: int, hkv: int):
     """Paged variant: the k axis walks the slot's page table (the index
     maps translate grid step -> physical pool page, as in
-    `_paged_decode_kernel`); each fetched page dequantizes in register."""
+    `_paged_decode_kernel`); each fetched page carries every KV head, and
+    head h's int8 rows dequantize in register against their
+    (page_size, 1) scale column."""
     del pt_ref
     bb = pl.program_id(0)
     jj = pl.program_id(1)
     length = len_ref[bb]
     last = jnp.maximum(0, (length - 1) // page_size)
+    g = q_ref.shape[1] // hkv
 
     @pl.when(jj == 0)
     def _init():
@@ -194,26 +203,13 @@ def _paged_quantized_decode_kernel(len_ref, pt_ref, q_ref, kq_ref, ks_ref,
 
     @pl.when(jj <= last)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                 # (g, dh)
-        k = kq_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-        v = vq_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (g, page_size)
-        k_pos = jj * page_size + jax.lax.broadcasted_iota(jnp.int32,
-                                                          s.shape, 1)
-        s = jnp.where(k_pos < length, s, NEG_INF)
-
-        m_prev = m_ref[...]                              # (g, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new <= NEG_INF, 0.0, p)          # fully-masked page
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for h in range(hkv):
+            rows = slice(h * g, (h + 1) * g)
+            _attend_rows(q_ref[0, rows, :], kq_ref[0, :, h, :],
+                         vq_ref[0, :, h, :], rows, jj * page_size, length,
+                         m_ref, l_ref, acc_ref, scale=scale,
+                         k_scale=ks_ref[0, :, h:h + 1],
+                         v_scale=vs_ref[0, :, h:h + 1])
 
     @pl.when(jj == max_pages - 1)
     def _store():
@@ -231,61 +227,61 @@ def paged_quantized_gqa_decode_attention(
     q: (B, Hq, dh); kq_pool, vq_pool: (num_pages, page_size, Hkv, dh)
     int8; ks_pool, vs_pool: (num_pages, page_size, Hkv) f32 per-row
     scales; pages: (B, max_pages) int32 page table; length: (B,) valid
-    prefixes.  Returns (B, Hq, dh).  Page-table translation and the
-    per-slot skip law are identical to `paged_gqa_decode_attention`; the
-    scale pools ride two extra inputs whose index maps drop the dh axis.
+    prefixes.  Returns (B, Hq, dh).  Page-table translation, the per-slot
+    skip law and the one-slot-per-grid-row blocking are identical to
+    `paged_gqa_decode_attention`; the scale pools ride two extra inputs
+    whose blocks drop the dh axis.
     """
     out_dtype = q.dtype
     q = q.astype(jnp.float32)
     b, hq, dh = q.shape
     num_pages, page_size, hkv, _ = kq_pool.shape
     max_pages = pages.shape[1]
-    g = hq // hkv
     if scale is None:
         scale = 1.0 / (dh ** 0.5)
     lengths = _row_lengths(length, b, max_pages * page_size)
-    lengths = jnp.repeat(lengths, hkv)              # row r -> slot r // hkv
     pt = jnp.asarray(pages, jnp.int32)
-    qf = q.reshape(b, hkv, g, dh).reshape(b * hkv, g, dh)
-    bkv = b * hkv
+
+    def page_of(r, j, len_ref, pt_ref):
+        last = jnp.maximum(0, (len_ref[r] - 1) // page_size)
+        return jnp.clip(pt_ref[r, jnp.minimum(j, last)], 0, num_pages - 1)
 
     def kv_index(r, j, len_ref, pt_ref):
-        last = jnp.maximum(0, (len_ref[r] - 1) // page_size)
-        page = pt_ref[r // hkv, jnp.minimum(j, last)]
-        return (jnp.clip(page, 0, num_pages - 1), 0, r % hkv, 0)
+        return (page_of(r, j, len_ref, pt_ref), 0, 0, 0)
 
     def scale_index(r, j, len_ref, pt_ref):
-        last = jnp.maximum(0, (len_ref[r] - 1) // page_size)
-        page = pt_ref[r // hkv, jnp.minimum(j, last)]
-        return (jnp.clip(page, 0, num_pages - 1), 0, r % hkv)
+        return (page_of(r, j, len_ref, pt_ref), 0, 0)
 
     fn = functools.partial(_paged_quantized_decode_kernel, scale=scale,
-                           page_size=page_size, max_pages=max_pages)
+                           page_size=page_size, max_pages=max_pages, hkv=hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bkv, max_pages),
+        grid=(b, max_pages),
         in_specs=[
-            pl.BlockSpec((1, g, dh), lambda r, j, len_ref, pt_ref: (r, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh), kv_index),
-            pl.BlockSpec((1, page_size, 1), scale_index),
-            pl.BlockSpec((1, page_size, 1, dh), kv_index),
-            pl.BlockSpec((1, page_size, 1), scale_index),
+            pl.BlockSpec((1, hq, dh), lambda r, j, len_ref, pt_ref: (r, 0, 0)),
+            pl.BlockSpec((1, page_size, hkv, dh), kv_index),
+            pl.BlockSpec((1, page_size, hkv), scale_index),
+            pl.BlockSpec((1, page_size, hkv, dh), kv_index),
+            pl.BlockSpec((1, page_size, hkv), scale_index),
         ],
-        out_specs=pl.BlockSpec((1, g, dh),
+        out_specs=pl.BlockSpec((1, hq, dh),
                                lambda r, j, len_ref, pt_ref: (r, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dh), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, 1), jnp.float32),
+            pltpu.VMEM((hq, dh), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         fn,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bkv, g, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
         interpret=interpret,
-    )(lengths, pt, qf, kq_pool, ks_pool, vq_pool, vs_pool)
-    return out.reshape(b, hkv, g, dh).reshape(b, hq, dh).astype(out_dtype)
+    )(lengths, pt, q, kq_pool, ks_pool, vq_pool, vs_pool)
+    return out.astype(out_dtype)
 
 
 def quantized_decode_ref(q: jax.Array, kq: jax.Array, ks: jax.Array,

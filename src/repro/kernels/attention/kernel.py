@@ -23,6 +23,12 @@ length instead of tripping a divisibility assert.
 
 Supports causal masking, sliding windows, and GQA (grouped q heads fold into
 the q-block row axis).
+
+`ragged_flash_attention` is the serve step's variant: a chunk of queries
+that continues a KV cache.  Row r's queries sit at absolute positions
+``q_offset[r] + i`` and see the first ``kv_len[r]`` cache rows, causally;
+both vectors ride scalar prefetch, so each q-block's K/V stream stops at
+its own last visible block (the same clamp-and-skip law, per row).
 """
 
 from __future__ import annotations
@@ -34,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import hardware
 from repro.core.cost_model import attention_max_k_steps
 
 NEG_INF = -1e30
@@ -97,28 +104,39 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             ok &= (q_pos - k_pos) < window
         if kv_len < k_steps * block_k:   # padded K/V tail
             ok &= k_pos < kv_len
-        s = jnp.where(ok, s, NEG_INF)
-
-        m_prev = m_ref[...]                              # (block_q, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # Rows with no surviving key yet sit at m == NEG_INF; exp(s - m)
-        # would turn fully-masked logits into 1s.  Zero them so l stays 0
-        # and the store's l-floor makes such rows output 0 — the pinned
-        # convention for degenerate rows (padded q/K tails, and window
-        # rows beyond the cache at sq > sk), shared with `ref.attention_ref`.
-        p = jnp.where(m_new <= NEG_INF, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        softmax_update(jnp.where(ok, s, NEG_INF), v, m_ref, l_ref, acc_ref)
 
     @pl.when(jj == grid_k - 1)
     def _store():
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def softmax_update(s, v, m_ref, l_ref, acc_ref, rows=slice(None),
+                   p_scale=None):
+    """One online-softmax step, shared by every attention kernel here and
+    in `decode.py`/`decode_int8.py`: fold the masked logits ``s``
+    (q rows, keys) and their V block into the running (m, l, acc) scratch
+    rows ``rows`` (a static slice; all rows by default).  ``p_scale``
+    (1, keys), if given, multiplies the probabilities' columns before the
+    PV product — the int8 cache's per-key V scales."""
+    m_prev = m_ref[rows, :]                              # (q rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    # Rows with no surviving key yet sit at m == NEG_INF; exp(s - m)
+    # would turn fully-masked logits into 1s.  Zero them so l stays 0
+    # and the store's l-floor makes such rows output 0 — the pinned
+    # convention for degenerate rows (padded q/K tails, and window
+    # rows beyond the cache at sq > sk), shared with `ref.attention_ref`.
+    p = jnp.where(m_new <= NEG_INF, 0.0, p)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[rows, :] = l_ref[rows, :] * corr + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[rows, :] = m_new
+    if p_scale is not None:
+        p = p * p_scale
+    acc_ref[rows, :] = acc_ref[rows, :] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -189,6 +207,120 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
         interpret=interpret,
     )(q, k, v)
+    return out[:, :sq] if q_pad else out
+
+
+def _ragged_last_step(qi, off, kv_len, *, block_q: int, block_k: int,
+                      k_steps: int):
+    """Last K-step q-block ``qi`` of a row at offset ``off`` with
+    ``kv_len`` valid keys can see (0 when it sees none: that block runs
+    fully masked and its rows output 0)."""
+    hi = jnp.minimum(off + (qi + 1) * block_q, kv_len) - 1
+    return jnp.clip(hi // block_k, 0, k_steps - 1)
+
+
+def _ragged_flash_kernel(off_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                         m_ref, l_ref, acc_ref, *, scale: float,
+                         block_q: int, block_k: int, k_steps: int):
+    r = pl.program_id(0)
+    qi = pl.program_id(1)
+    jj = pl.program_id(2)
+    off = off_ref[r]
+    kv_len = len_ref[r]
+
+    @pl.when(jj == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    last = _ragged_last_step(qi, off, kv_len, block_q=block_q,
+                             block_k=block_k, k_steps=k_steps)
+
+    @pl.when(jj <= last)
+    def _compute():
+        v = v_ref[0]
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
+        q_pos = off + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        k_pos = jj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ok = (q_pos >= k_pos) & (k_pos < kv_len)
+        softmax_update(jnp.where(ok, s, NEG_INF), v, m_ref, l_ref, acc_ref)
+
+    @pl.when(jj == k_steps - 1)
+    def _store():
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def ragged_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                           q_offset: jax.Array, kv_len: jax.Array, *,
+                           scale: float, block_q: int = 512,
+                           block_k: int = 512,
+                           interpret: bool = False) -> jax.Array:
+    """Causal attention of queries that continue a cache.
+
+    q: (BH, Sq, dh) — row r's query i sits at position ``q_offset[r] + i``;
+    k, v: (BH, Sk, dh) — the cache, of which the first ``kv_len[r]`` rows
+    are valid for row r.  ``q_offset``/``kv_len``: (BH,) int32.  A query
+    sees key j iff ``j <= q_pos`` and ``j < kv_len``; a query that sees no
+    key outputs 0.  K/V blocks past a q-block's last visible one are
+    neither streamed nor multiplied (the index map clamps onto it).
+    """
+    bh, sq, dh = q.shape
+    _, sk, _ = k.shape
+    block_q = min(block_q, sq)
+    block_k = min(block_k, sk)
+    q_pad = -sq % block_q
+    k_pad = -sk % block_k
+    if q_pad:
+        q = jnp.pad(q, ((0, 0), (0, q_pad), (0, 0)))
+    if k_pad:
+        k = jnp.pad(k, ((0, 0), (0, k_pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, k_pad), (0, 0)))
+    k_steps = (sk + k_pad) // block_k
+    q_blocks = (sq + q_pad) // block_q
+
+    def kv_index(r, i, j, off_ref, len_ref):
+        last = _ragged_last_step(i, off_ref[r], len_ref[r], block_q=block_q,
+                                 block_k=block_k, k_steps=k_steps)
+        return (r, jnp.minimum(j, last), 0)
+
+    def q_index(r, i, j, off_ref, len_ref):
+        return (r, i, 0)
+
+    fn = functools.partial(_ragged_flash_kernel, scale=scale,
+                           block_q=block_q, block_k=block_k, k_steps=k_steps)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(bh, q_blocks, k_steps),
+        in_specs=[
+            pl.BlockSpec((1, block_q, dh), q_index),
+            pl.BlockSpec((1, block_k, dh), kv_index),
+            pl.BlockSpec((1, block_k, dh), kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, dh), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, dh), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        fn,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, sq + q_pad, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
+        interpret=interpret,
+    )(jnp.asarray(q_offset, jnp.int32), jnp.asarray(kv_len, jnp.int32),
+      q, k, v)
     return out[:, :sq] if q_pad else out

@@ -140,7 +140,8 @@ def _attn_build_launcher(problem: dict, knobs: dict, interpret: bool):
         block_k=knobs["block_k"], interpret=interpret)
 
 
-def _attn_problem_fn(q, k, v, causal=True, window=None) -> tuple[dict, object]:
+def _attn_problem_fn(q, k, v, causal=True, window=None, q_offset=None,
+                     kv_len=None) -> tuple[dict, object]:
     b, sq, hq, dh = q.shape
     _, sk, _, _ = k.shape
     return {"bh": b * hq, "sq": sq, "sk": sk, "dh": dh,
@@ -148,16 +149,19 @@ def _attn_problem_fn(q, k, v, causal=True, window=None) -> tuple[dict, object]:
 
 
 def _attn_run_fn(plan: registry.Plan, q, k, v, *, interpret=False,
-                 causal=True, window=None):
+                 causal=True, window=None, q_offset=None, kv_len=None):
     return attn_ops.mha_attention(q, k, v, causal=causal, window=window,
                                   block_q=plan.knobs["block_q"],
                                   block_k=plan.knobs["block_k"],
-                                  interpret=interpret, use_kernel=True)
+                                  interpret=interpret, use_kernel=True,
+                                  q_offset=q_offset, kv_len=kv_len)
 
 
-def _attn_reference_fn(q, k, v, causal=True, window=None):
+def _attn_reference_fn(q, k, v, causal=True, window=None, q_offset=None,
+                       kv_len=None):
     return attn_ops.mha_attention(q, k, v, causal=causal, window=window,
-                                  use_kernel=False)
+                                  use_kernel=False, q_offset=q_offset,
+                                  kv_len=kv_len)
 
 
 registry.register(registry.KernelSpec(

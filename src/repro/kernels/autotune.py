@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from repro.core import hardware, ioutil, tiling
 from repro.kernels import registry
 from repro.kernels.registry import KernelSpec, Plan
+from repro.runtime.faults import KernelDispatchFault
 
 # v3: the declarative KernelSpec registry unified the four per-family
 # pipelines and entry formats ({"knobs": ..., "detail": ...} instead of
@@ -278,14 +279,26 @@ def tune(
     best, best_us = None, float("inf")
     if measurable and cands:
         inputs = spec.make_inputs(problem, dtype)
+        refused = []
         for c in cands[:measure_k]:
             fn = spec.build_launcher(problem, c.knobs, interpret=interpret)
             try:
                 us = measure(lambda fn=fn: fn(*inputs))
-            except Exception:
-                continue  # e.g. real VMEM overflow the model missed
+            except Exception as e:  # the compiler refused this candidate
+                # (e.g. a VMEM overflow the model missed): say so, and
+                # measure the rest — a plan is never chosen in silence.
+                refused.append((c.knobs, e))
+                warnings.warn(
+                    f"{spec.name} candidate {c.knobs} failed on {backend} "
+                    f"and was not measured: {e!r}", RuntimeWarning,
+                    stacklevel=2)
+                continue
             if us < best_us:
                 best, best_us = c, us
+        if best is None:
+            raise RuntimeError(
+                f"every measured {spec.name} candidate for {key} failed on "
+                f"{backend}: {refused}")
     if best is not None:
         chosen, source, measured_us = best, "measured", best_us
     else:
@@ -335,12 +348,14 @@ def dispatch(family: str, *args, cache: TuneCache | None = None,
     measured winners then come from offline callers through the shared
     cache).
 
-    Graceful degradation: a kernel launch that raises (real Pallas
-    failure, or the chaos hook) falls back one-shot to the family's
-    pure-jnp reference path — numerically equivalent, just slower — and
-    the plan is marked poisoned in the cache so the next tune re-runs the
-    DSE instead of re-serving the knobs that just failed.  A serving
-    request must complete slowly, not die on a kernel.
+    Graceful degradation under chaos injection: an injected
+    `KernelDispatchFault` (the hook installed by `install_dispatch_hook`)
+    falls back one-shot to the family's pure-jnp reference path —
+    numerically equivalent, just slower — and the plan is marked poisoned
+    in the cache so the next tune re-runs the DSE instead of re-serving
+    the knobs that just failed.  Any other exception — a kernel the
+    compiler refused, a bad shape — propagates: on a chip, a silent jnp
+    fallback would hide that the kernel never ran.
     """
     spec = registry.get(family)
     if use_kernel is None:
@@ -356,7 +371,7 @@ def dispatch(family: str, *args, cache: TuneCache | None = None,
         if _dispatch_fault_hook is not None:
             _dispatch_fault_hook(family)
         return spec.run_fn(plan, *args, interpret=interpret, **kwargs)
-    except Exception as e:
+    except KernelDispatchFault as e:
         mark_plan_poisoned(plan.key, cache=cache)
         warnings.warn(
             f"kernel dispatch for family '{family}' failed ({e!r}); "
@@ -663,7 +678,8 @@ def predict_decode_step_us(cfg, batch: int, *, cache_len: int,
                            lengths: Sequence[int] | None = None,
                            plans: list[OpPlan] | None = None,
                            cache: TuneCache | None = None,
-                           block_k: int | None = None) -> float:
+                           block_k: int | None = None,
+                           chip: hardware.Chip = hardware.TPU_V5E) -> float:
     """Predicted wall time of one decode step at this batch, from the tuned
     plans' model times.
 
@@ -683,6 +699,9 @@ def predict_decode_step_us(cfg, batch: int, *, cache_len: int,
     re-priced term: the paged decode kernel streams one *page* per grid
     step, so a paged server prices the stream at its page size rather
     than the contiguous plan's tuned block.
+
+    ``chip`` supplies the re-priced KV term's constants (the server passes
+    the row `hardware.chip_for` found for the device it runs on).
     """
     lengths = lengths or None            # empty == no distribution
     plans = plans if plans is not None else plan_for_model(
@@ -709,12 +728,12 @@ def predict_decode_step_us(cfg, batch: int, *, cache_len: int,
             if jnp.dtype(kv_dtype) == jnp.int8:
                 model = cost_model.quantized_decode_time_model(
                     prob["bkv"], prob["g"], prob["cache_len"], prob["dh"],
-                    bk, lengths=list(lengths))
+                    bk, chip=chip, lengths=list(lengths))
             else:
                 model = cost_model.decode_time_model(
                     prob["bkv"], prob["g"], prob["cache_len"], prob["dh"],
                     bk, dtype_bytes=jnp.dtype(kv_dtype).itemsize,
-                    lengths=list(lengths))
+                    chip=chip, lengths=list(lengths))
             kv_us = n_attn * model["time_s"] * 1e6
         else:
             kv_us = n_attn * decode_plan.plan.model_time_us
@@ -728,7 +747,7 @@ def predict_decode_step_us(cfg, batch: int, *, cache_len: int,
         else:
             kv_bytes = (2.0 * streamed * cfg.kv_dim
                         * jnp.dtype(kv_dtype).itemsize)        # K+V stream
-        kv_us = n_attn * kv_bytes / hardware.TPU_V5E.hbm_bw * 1e6
+        kv_us = n_attn * kv_bytes / chip.hbm_bw * 1e6
     return (n_attn * attn_us + cfg.num_layers * ffn_us + logits_us + kv_us)
 
 
@@ -741,6 +760,7 @@ def select_serving_batch(
     cache: TuneCache | None = None,
     pool_pages: int | None = None,
     page_size: int | None = None,
+    chip: hardware.Chip = hardware.TPU_V5E,
 ) -> dict:
     """Sweep candidate batch sizes against the tuned plans' predicted step
     time; pick the batch maximizing predicted decode throughput under the
@@ -796,7 +816,7 @@ def select_serving_batch(
         step_us = predict_decode_step_us(cfg, b, cache_len=cache_len,
                                          kv_dtype=kv_dtype, plans=plans,
                                          lengths=lengths_b,
-                                         block_k=page_size)
+                                         block_k=page_size, chip=chip)
         tok_per_s = b / (step_us * 1e-6)
         feasible = (latency_budget_ms is None
                     or step_us <= latency_budget_ms * 1e3)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import tiling
+from repro.core import hardware, tiling
 
 # Fused epilogue nonlinearities.  Static strings (jit/cache friendly) rather
 # than callables; extend here when a new serving activation shows up.
@@ -109,7 +109,8 @@ def blocked_matmul(
         scratch_shapes=[pltpu.VMEM((y, x), jnp.float32)],
         # M/N grid axes are independent; only the K axis carries the
         # accumulator, so Mosaic may parallelize the first two.
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
         interpret=interpret,
     )(*operands)

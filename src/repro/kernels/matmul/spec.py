@@ -40,10 +40,10 @@ def rank_tiles(
 
     def evaluate(knobs: dict) -> tuple[float, dict]:
         y, x = knobs["y"], knobs["x"]
-        z_budget = (budget - y * x * 4) // max((y + 2 * x) * dtype_bytes, 1)
+        z_budget = tiling.max_depth(y, x, budget, dtype_bytes)
         z = max(align, (min(z_budget, k) // align) * align)
         t = tiling.Tile(y, x, z)
-        if t.vmem_elems() * dtype_bytes + y * x * 4 > budget + y * x * dtype_bytes:
+        if t.kernel_vmem_bytes(dtype_bytes) > budget:
             return float("inf"), {}
         res = cost_model.matmul_time_model(m, n, k, t, dtype_bytes=dtype_bytes)
         return res["time_s"], {"tile": t, **res}

@@ -28,7 +28,7 @@ import repro.configs as configs
 from repro.configs.shapes import SHAPES, applicable
 from repro.core import cost_model, estimate, hlo_stats
 from repro.launch import policy, specs, steps
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.optim import adamw
 from repro.parallel import sharding as shd
 
@@ -222,7 +222,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     record = {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
               "variant": variant, "chips": chips, "status": "ok"}
 
-    with set_mesh(mesh), shd.use_rules(rules):
+    with jax.set_mesh(mesh), shd.use_rules(rules):
         t0 = time.time()
         lowered, tokens, model_flops = _lower_step(
             cfg, shape, mesh, rules, step_kwargs=step_kwargs,

@@ -1,106 +1,26 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS (not module constants) so importing this module never
-touches jax device state.  The jax API points that moved across the
-pinned-version boundary (``jax.sharding.AxisType``, ``jax.set_mesh``,
-``jax.make_mesh(axis_types=...)``, ``jax.sharding.get_abstract_mesh``,
-``jax.shard_map``) are wrapped in compat helpers here so every caller —
-including ``parallel/compression.py`` and ``models/moe.py``, which import
-them lazily inside the function body to keep the layer diagram acyclic —
-runs on jax 0.4.x and newer alike.
+touches jax device state.  Every mesh axis is ``AxisType.Auto``: the models
+rely on GSPMD propagation.
 """
 
 from __future__ import annotations
 
-import contextlib
 import math
 
 import jax
 
 
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,) * n}`` where supported, ``{}`` otherwise.
-
-    ``jax.sharding.AxisType`` does not exist on older pinned jax versions
-    (e.g. 0.4.37), where every mesh axis is implicitly Auto — so omitting
-    the kwarg there is semantically identical, not a downgrade.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` is the modern spelling; on jax versions predating it
-    the ``Mesh`` object itself is the context manager with the same scope
-    semantics.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return contextlib.nullcontext(mesh) if mesh is None else mesh
-
-
-def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists.
-
-    Older jax (0.4.x) has no ``axis_types`` parameter — and no axis types
-    at all, so every axis is implicitly Auto and omitting the kwarg is
-    semantically identical.
-    """
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         **axis_types_kwargs(len(tuple(axis_names))))
-
-
-def get_abstract_mesh():
-    """The ambient mesh: ``jax.sharding.get_abstract_mesh()`` where it
-    exists, the 0.4.x thread-resources physical mesh otherwise (both are
-    what ``set_mesh`` above installed)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as _mesh_lib
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across the API move.
-
-    Modern jax spells partial-manual mode ``axis_names={...}`` and replica
-    checking ``check_vma``; 0.4.x has ``jax.experimental.shard_map`` with
-    the complement ``auto={...}`` and ``check_rep``.  Checking is disabled
-    on both: the repo's callers reduce manually (psum/pmean) inside the
-    mapped body.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False, **kw)
-        except TypeError:  # pre-rename spelling of the same knob
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, **kw)
-
-
 def _mesh(shape, axes) -> jax.sharding.Mesh:
-    # Auto axis types: the models rely on GSPMD propagation.  Pin the device
-    # subset explicitly so a 512-device dry-run host can build a 256-chip pod.
+    # Pin the device subset explicitly so a 512-device dry-run host can
+    # build a 256-chip pod.
     n = math.prod(shape)
     devices = jax.devices()[:n]
     from jax.experimental import mesh_utils
     dmesh = mesh_utils.create_device_mesh(shape, devices=devices)
-    return jax.sharding.Mesh(dmesh, axes, **axis_types_kwargs(len(axes)))
+    return jax.sharding.Mesh(
+        dmesh, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -110,5 +30,5 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Tiny mesh over whatever devices exist (CPU tests)."""
+    """Tiny mesh over whatever devices exist (CPU tests, one-chip serving)."""
     return _mesh((data, model), ("data", "model"))

@@ -19,8 +19,9 @@ The robustness layer on top (see docs/ROBUSTNESS.md):
 * a per-slot NaN/Inf logits guard — a poisoned slot is quarantined alone
   (reset + requeued with backoff) while its neighbours keep decoding
   bitwise-identically;
-* kernel-dispatch failure falls back one-shot to the jnp reference step
-  with the plan marked poisoned for re-tune;
+* an injected kernel-dispatch fault falls back one-shot to the jnp
+  reference step with the plan marked poisoned for re-tune (a real
+  kernel failure propagates);
 * per-request deadlines (TTFT and total) and retry-with-backoff, with the
   drain loop failing loudly (lifecycle table) instead of spinning when no
   progress is possible;
@@ -54,9 +55,10 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as configs
+from repro.core import hardware
 from repro.kernels import autotune
-from repro.launch import steps
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch import compile_cache, steps
+from repro.launch.mesh import make_host_mesh
 from repro.launch import specs
 from repro.launch.scheduler import POLICIES, Scheduler
 from repro.models import transformer
@@ -689,6 +691,17 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
             "snapshots_saved": 0 if snapshots is None else snapshots.saved}
 
 
+def serving_chip() -> hardware.Chip:
+    """Constants of the chip this process serves on.  On a TPU they are
+    looked up by the ``device_kind`` JAX reports, and a kind with no row
+    in `hardware.CHIPS` raises; elsewhere (CPU runs) the modelled target,
+    `hardware.TPU_V5E`."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return hardware.chip_for(dev.device_kind)
+    return hardware.TPU_V5E
+
+
 def build_fault_plan(*, chaos: bool, fault_seed: int, crash: bool,
                      crash_step: int | None = None):
     """The run's fault schedule: the smoke plan (--chaos), a seeded crash
@@ -1024,7 +1037,7 @@ def _run_resume(args) -> int:
     rules = specs.rules_for(mesh)
     t0 = time.time()
     try:
-        with set_mesh(mesh), shd.use_rules(rules):
+        with jax.set_mesh(mesh), shd.use_rules(rules):
             R = prepare_resume(args.state_dir)
             server, lc, serving = R["server"], R["lc"], R["serving"]
             if R["injector"] is not None:
@@ -1034,7 +1047,8 @@ def _run_resume(args) -> int:
                 kv_dtype=server.kv_dtype,
                 lengths=autotune._quantile_lengths(
                     server.batch, serving["dist"], server.max_len),
-                plans=server.kernel_plan) if server.kernel_plan else None)
+                plans=server.kernel_plan, chip=serving_chip())
+                if server.kernel_plan else None)
             watchdog = fault_tolerance.DecodeWatchdog(predicted_us)
             prep_s = time.time() - t0
             print(json.dumps({"recovery": {**R["recovery"],
@@ -1086,7 +1100,7 @@ def _run_resume(args) -> int:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_14b",
-                    choices=configs.list_archs())
+                    choices=configs.list_archs() + configs.list_cuts())
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--batch", type=int, default=0,
@@ -1161,6 +1175,7 @@ def main(argv=None):
                     help="resume a crashed run from --state-dir instead "
                          "of starting fresh")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.resume:
         if not args.state_dir:
@@ -1173,6 +1188,7 @@ def main(argv=None):
         return 0
     kv_dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16,
                 "int8": jnp.int8}[args.kv_dtype]
+    chip = serving_chip()
     mesh = make_host_mesh(data=1, model=1)
     rules = specs.rules_for(mesh)
 
@@ -1218,7 +1234,7 @@ def main(argv=None):
             slot_lengths=dist,
             latency_budget_ms=args.latency_budget_ms,
             pool_pages=(args.pool_pages or None) if args.paged else None,
-            page_size=args.page_size if args.paged else None)
+            page_size=args.page_size if args.paged else None, chip=chip)
         decision["source"] = "autotune"
         batch = decision["batch"]
     print(json.dumps({"serving_plan": decision}))
@@ -1270,7 +1286,8 @@ def main(argv=None):
             decision.get("predicted_step_us")
             or autotune.predict_decode_step_us(
                 cfg, batch, cache_len=max_len, kv_dtype=kv_dtype,
-                lengths=autotune._quantile_lengths(batch, dist, max_len)))
+                lengths=autotune._quantile_lengths(batch, dist, max_len),
+                chip=chip))
         clock = loadgen.VirtualClock(step_us * 1e-6)
         source = loadgen.TraceSource(trace, cfg.vocab_size)
         lc = Lifecycle(queue_limit=args.queue_limit,
@@ -1319,7 +1336,7 @@ def main(argv=None):
         })
 
     try:
-        with set_mesh(mesh), shd.use_rules(rules):
+        with jax.set_mesh(mesh), shd.use_rules(rules):
             server = Server(cfg, batch, max_len,
                             prefill_len=prefill_len,
                             slot_lengths=dist, injector=injector,
@@ -1330,7 +1347,7 @@ def main(argv=None):
             predicted_us = (autotune.predict_decode_step_us(
                 cfg, batch, cache_len=max_len, kv_dtype=kv_dtype,
                 lengths=autotune._quantile_lengths(batch, dist, max_len),
-                plans=server.kernel_plan)
+                plans=server.kernel_plan, chip=chip)
                 if server.kernel_plan else None)
             watchdog = fault_tolerance.DecodeWatchdog(predicted_us)
             t0 = time.time()

@@ -1,7 +1,9 @@
 """Dry-run sweep driver: one subprocess per (arch x shape x mesh) cell.
 
 Per-cell isolation keeps one failed compile from killing the sweep and
-bounds memory growth.  Single-pod cells run with differential cost probes
+bounds memory growth.  The dry run fakes 512 host devices, so it is a
+CPU-only tool: every child runs with ``JAX_PLATFORMS=cpu`` and never takes
+an accelerator.  Single-pod cells run with differential cost probes
 (they feed the roofline table); multi-pod cells prove lowering/compile +
 memory only (the brief's roofline table is single-pod).
 
@@ -13,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
@@ -71,7 +74,8 @@ def main(argv=None) -> int:
         t0 = time.time()
         try:
             proc = subprocess.run(cmd, capture_output=True, text=True,
-                                  timeout=args.timeout)
+                                  timeout=args.timeout,
+                                  env={**os.environ, "JAX_PLATFORMS": "cpu"})
             rc = proc.returncode
         except subprocess.TimeoutExpired:
             rc = -9

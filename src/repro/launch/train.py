@@ -24,8 +24,7 @@ import repro.configs as configs
 from repro.data import DataConfig, make_source
 from repro.checkpoint import CheckpointManager
 from repro.launch import policy, specs, steps
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                               set_mesh)
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer
 from repro.optim import adamw
 from repro.parallel import sharding as shd
@@ -97,7 +96,7 @@ def main(argv=None):
     train_step = jax.jit(steps.make_train_step(cfg, opt_cfg),
                          donate_argnums=(0,))
 
-    with set_mesh(mesh), shd.use_rules(rules):
+    with jax.set_mesh(mesh), shd.use_rules(rules):
         state, state_sh = build_state(cfg, opt_cfg, jax.random.PRNGKey(0),
                                       mesh, rules)
         start_step = 0

@@ -268,10 +268,14 @@ def attention_apply(
         # Valid prefix after the write, per slot (inactive: unchanged).
         new_len = lengths + jnp.sum(act2d, axis=1, dtype=jnp.int32)
         mode = os.environ.get("REPRO_DECODE_KERNEL", "auto")
-        use_fused = (s == 1 and cfg.causal and not cfg.sliding_window
-                     and mode != "off"
-                     and (mode == "interpret"
-                          or jax.default_backend() == "tpu"))
+        kernels_on = (cfg.causal and not cfg.sliding_window
+                      and mode != "off"
+                      and (mode == "interpret"
+                           or jax.default_backend() == "tpu"))
+        # S == 1 is the decode hot loop (fused decode kernels); S > 1 is a
+        # prefill or chunk continuing the cache (ragged flash kernel).
+        use_fused = kernels_on and s == 1
+        use_flash = kernels_on and s > 1
         if paged is not None and pages is not None:
             # Paged cache: ck/cv are the layer's physical page pools
             # (num_pages, page_size, Hkv, dh) shared by every slot; the
@@ -322,11 +326,15 @@ def attention_apply(
                     vg = quantize.dequantize_rows(
                         vg, cvs[safe].reshape(b, mp * psz,
                                               cfg.num_kv_heads))
-                k_pos = jnp.arange(mp * psz, dtype=jnp.int32)
-                k_valid = k_pos[None, :] < new_len[:, None]
-                out = attention_core(q, kg, vg, pos_b, k_pos,
-                                     causal=cfg.causal, window=None,
-                                     scale=scale, k_valid=k_valid)
+                if use_flash:
+                    out = _chunk_attention(q, kg, vg, lengths, new_len,
+                                           interpret=(mode == "interpret"))
+                else:
+                    k_pos = jnp.arange(mp * psz, dtype=jnp.int32)
+                    k_valid = k_pos[None, :] < new_len[:, None]
+                    out = attention_core(q, kg, vg, pos_b, k_pos,
+                                         causal=cfg.causal, window=None,
+                                         scale=scale, k_valid=k_valid)
             new_cache = {"k": ck, "v": cv}
             if quantized:
                 new_cache.update({"k_scale": cks, "v_scale": cvs})
@@ -387,10 +395,14 @@ def attention_apply(
             if quantized:
                 kr = quantize.dequantize_rows(ck, cks)
                 vr = quantize.dequantize_rows(cv, cvs)
-            out = attention_core(q, kr, vr, pos_b, k_pos,
-                                 causal=cfg.causal,
-                                 window=cfg.sliding_window, scale=scale,
-                                 k_valid=k_valid)
+            if use_flash:
+                out = _chunk_attention(q, kr, vr, lengths, new_len,
+                                       interpret=(mode == "interpret"))
+            else:
+                out = attention_core(q, kr, vr, pos_b, k_pos,
+                                     causal=cfg.causal,
+                                     window=cfg.sliding_window, scale=scale,
+                                     k_valid=k_valid)
         new_cache = {"k": ck, "v": cv}
         if quantized:
             new_cache.update({"k_scale": cks, "v_scale": cvs})
@@ -398,6 +410,17 @@ def attention_apply(
     out = out.reshape(b, s, cfg.q_dim).astype(x.dtype)
     y = out @ gather_weight(params["wo"]).astype(x.dtype)
     return constrain(y, "batch", "res_seq", "embed"), new_cache
+
+
+def _chunk_attention(q, k, v, lengths, new_len, *, interpret: bool):
+    """Prefill / chunked prefill through the cache on the registry's flash
+    kernel: slot b's S queries sit at ``lengths[b] + i`` and see the first
+    ``new_len[b]`` rows of its (just written) cache view ``k``/``v``
+    (B, L, Hkv, dh), causally.  q is upcast to the cache dtype, as the
+    decode kernels do."""
+    from repro.kernels.autotune import dispatch
+    return dispatch("attention", q.astype(k.dtype), k, v, causal=True,
+                    q_offset=lengths, kv_len=new_len, interpret=interpret)
 
 
 def attention_cache_init(cfg, batch: int, cache_len: int, dtype=jnp.bfloat16,

@@ -171,10 +171,8 @@ def apply_sharded(params: Params, x: jax.Array, cfg, mesh=None):
 
     model_axis = rules.table["experts"][0]
     batch_axes = tuple(rules.table.get("batch") or ())
-    # Lazy import: the cross-version jax shims live in launch/mesh.py.
-    from repro.launch import mesh as mesh_compat
     if mesh is None:
-        mesh = mesh_compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
     n_shards = mesh.shape[model_axis]
     e = cfg.num_experts
     if e % n_shards:
@@ -262,13 +260,15 @@ def apply_sharded(params: Params, x: jax.Array, cfg, mesh=None):
 
     manual = frozenset(batch_axes) | {model_axis}
     batch_spec = P(tuple(batch_axes) if batch_axes else None, seq_axes, None)
-    out, aux = mesh_compat.shard_map(
+    # Replica checking off: the body reduces manually (psum/pmean).
+    out, aux = jax.shard_map(
         local_moe,
-        mesh,
+        mesh=mesh,
         in_specs=(P(None, None), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None),
                   batch_spec),
         out_specs=(batch_spec, P()),
         axis_names=manual,
+        check_vma=False,
     )(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)
     return out, jnp.mean(aux)

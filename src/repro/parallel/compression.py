@@ -25,11 +25,8 @@ def compressed_psum(x: jax.Array, axis_names, mesh=None) -> jax.Array:
     int32, and dequantize.  ``x`` must be replicated-layout on the reduced
     axes.  Quantization error per element is bounded by scale/2.
     """
-    # Lazy import: the compat shims live in launch/mesh.py (jax-only, no
-    # cycle) so one module owns every cross-version jax API point.
-    from repro.launch import mesh as mesh_compat
     if mesh is None:
-        mesh = mesh_compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
     axes = (axis_names,) if isinstance(axis_names, str) else tuple(axis_names)
     name = axes if len(axes) > 1 else axes[0]
     count = 1
@@ -53,5 +50,6 @@ def compressed_psum(x: jax.Array, axis_names, mesh=None) -> jax.Array:
         return out[:n].reshape(xv.shape) / count
 
     manual = frozenset(axes)
-    return mesh_compat.shard_map(local, mesh, P(), P(),
-                                 axis_names=manual)(x)
+    # Replica checking off: the body reduces manually (pmax/psum).
+    return jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
+                         axis_names=manual, check_vma=False)(x)

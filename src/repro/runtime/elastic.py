@@ -29,13 +29,12 @@ def largest_mesh_shape(num_devices: int, model_parallel: int,
 
 
 def remesh(devices, model_parallel: int) -> jax.sharding.Mesh:
-    from repro.launch.mesh import axis_types_kwargs
     data, model = largest_mesh_shape(len(devices), model_parallel)
     used = devices[: data * model]
     import numpy as np
     dmesh = np.asarray(used).reshape(data, model)
     return jax.sharding.Mesh(dmesh, ("data", "model"),
-                             **axis_types_kwargs(2))
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def reshard_state(state_host, mesh: jax.sharding.Mesh, pspecs):

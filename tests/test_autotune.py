@@ -13,6 +13,7 @@ from repro.kernels import autotune
 from repro.kernels.matmul.ref import matmul_ref
 from repro.kernels.spmv import pack_csr, spmv
 from repro.kernels.spmv.ref import spmv_ell_ref
+from repro.runtime.faults import KernelDispatchFault
 
 KEY = jax.random.PRNGKey(0)
 
@@ -168,7 +169,7 @@ def test_poisoned_plan_is_retuned_not_served(cache):
 
 
 def test_dispatch_fault_falls_back_to_reference_and_poisons_plan(cache):
-    """A kernel launch that raises (here: the chaos hook) must fall back
+    """An injected kernel-dispatch fault (the chaos hook) must fall back
     one-shot to the jnp reference — numerically identical result — and
     poison the plan so the next tune re-runs the DSE."""
     a = jax.random.normal(KEY, (96, 64), jnp.float32)
@@ -177,7 +178,7 @@ def test_dispatch_fault_falls_back_to_reference_and_poisons_plan(cache):
 
     def hook(family):
         calls.append(family)
-        raise RuntimeError("injected kernel-dispatch fault")
+        raise KernelDispatchFault("injected kernel-dispatch fault")
 
     autotune.install_dispatch_hook(hook)
     try:
@@ -198,6 +199,27 @@ def test_dispatch_fault_falls_back_to_reference_and_poisons_plan(cache):
     np.testing.assert_allclose(np.asarray(out2),
                                np.asarray(matmul_ref(a, b)),
                                rtol=5e-4, atol=5e-4)
+    assert not any(e.get("poisoned")
+                   for e in cache._load()["entries"].values())
+
+
+def test_dispatch_propagates_a_real_kernel_failure(cache):
+    """Only the injected fault class degrades to the reference: any other
+    exception from a kernel launch (a compiler refusal, a bad shape)
+    propagates, the plan stays unpoisoned, and nothing warns of a
+    fallback — a chip run must never finish on jnp in silence."""
+    a = jax.random.normal(KEY, (96, 64), jnp.float32)
+    b = jax.random.normal(jax.random.PRNGKey(1), (64, 80), jnp.float32)
+
+    def hook(family):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    autotune.install_dispatch_hook(hook)
+    try:
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            autotune.dispatch("matmul", a, b, interpret=True, cache=cache)
+    finally:
+        autotune.install_dispatch_hook(None)
     assert not any(e.get("poisoned")
                    for e in cache._load()["entries"].values())
 
@@ -256,6 +278,40 @@ def test_measurement_path_records_wall_time(cache):
     p = autotune.tune_matmul(128, 128, 128, cache=cache, measure_k=2)
     assert p.source == "measured"
     assert p.measured_us is not None and p.measured_us > 0
+
+
+def test_refused_candidate_is_reported_not_skipped_in_silence(
+        cache, monkeypatch):
+    """A candidate whose launch fails (a compiler refusal on a chip) is
+    named in a warning, and the rest are still measured."""
+    real = autotune.measure
+    calls = []
+
+    def measure(fn, *a, **kw):
+        calls.append(fn)
+        if len(calls) == 1:
+            raise RuntimeError("VMEM exceeded")
+        return real(fn, *a, **kw)
+
+    monkeypatch.setattr(autotune, "measure", measure)
+    with pytest.warns(RuntimeWarning, match="was not measured"):
+        p = autotune.tune("matmul", {"m": 128, "n": 256, "k": 128},
+                          cache=cache, measure_k=3)
+    assert len(calls) >= 2 and p.source == "measured"
+
+
+def test_every_candidate_refused_raises(cache, monkeypatch):
+    """No plan is chosen when nothing could be measured: the tuner raises
+    rather than serve an unmeasured "model" plan."""
+    def measure(fn, *a, **kw):
+        raise RuntimeError("VMEM exceeded")
+
+    monkeypatch.setattr(autotune, "measure", measure)
+    with pytest.warns(RuntimeWarning):
+        with pytest.raises(RuntimeError, match="every measured matmul"):
+            autotune.tune("matmul", {"m": 128, "n": 256, "k": 128},
+                          cache=cache, measure_k=3)
+    assert not cache._load()["entries"]
 
 
 # ---------------------------------------------------------------------------

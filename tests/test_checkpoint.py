@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager
-from repro.launch import mesh as mesh_compat
 
 
 def _state(seed=0):
@@ -75,7 +74,8 @@ def test_restore_with_target_shardings(tmp_path):
     mgr = CheckpointManager(tmp_path)
     state = _state()
     mgr.save(7, state, blocking=True)
-    mesh = mesh_compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(
         lambda _: jax.sharding.NamedSharding(mesh,
                                              jax.sharding.PartitionSpec()),

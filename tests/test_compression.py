@@ -10,22 +10,21 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.launch.mesh import axis_types_kwargs, set_mesh, shard_map
 from repro.parallel.compression import compressed_psum
 
 mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(4), ("data",),
-                         **axis_types_kwargs(1))
+                         axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 # per-device distinct values, laid out sharded on a leading axis then summed
 vals = rng.standard_normal((4, 300)).astype(np.float32) * 5
 x = jnp.asarray(vals)
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     # build a device-varying replicated-layout tensor via shard_map
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None)))
     out = jax.jit(lambda v: compressed_psum(
-        shard_map(lambda t: t[0], mesh,
-                  in_specs=P("data", None), out_specs=P(None))(v),
+        jax.shard_map(lambda t: t[0], mesh=mesh, in_specs=P("data", None),
+                      out_specs=P(None), check_vma=False)(v),
         "data"))(xs)
 ref = vals.mean(axis=0)
 err = np.abs(np.asarray(out) - ref)

@@ -81,6 +81,52 @@ def test_decode_through_fused_kernel_matches_teacher_forcing(
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.int8])
+def test_chunked_prefill_through_flash_kernel_matches_teacher_forcing(
+        kv_dtype, monkeypatch, tmp_path):
+    """The serve step's S > 1 path: two ragged prompts prefilled in one
+    chunk through the ragged flash kernel, then a second chunk continuing
+    both caches from their own depths, then one fused decode step — each
+    slot's logits must equal the teacher-forced forward of its sequence."""
+    monkeypatch.setenv("REPRO_DECODE_KERNEL", "interpret")
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE",
+                       str(tmp_path / "autotune.json"))
+    cfg = CASES["dense"]
+    params = transformer.init(cfg, KEY)
+    lens = np.array([10, 6])
+    toks = np.asarray(jax.random.randint(KEY, (2, 16), 0, cfg.vocab_size))
+    cache = transformer.cache_init(cfg, 2, 16, dtype=kv_dtype)
+    got = {0: [], 1: []}
+
+    def step(chunk, active):
+        nonlocal cache
+        lg, cache, _ = transformer.forward(
+            cfg, params, {"tokens": jnp.asarray(chunk)}, cache=cache,
+            active=jnp.asarray(active), compute_dtype=jnp.float32)
+        return np.asarray(lg)
+
+    lg = step(toks[:, :10], np.arange(10)[None] < lens[:, None])
+    for s_ in range(2):
+        got[s_] += list(lg[s_, :lens[s_]])
+    # second chunk: 3 more tokens each, from each slot's own depth
+    chunk = np.stack([toks[s_, lens[s_]:lens[s_] + 3] for s_ in range(2)])
+    lg = step(chunk, np.ones((2, 3), bool))
+    for s_ in range(2):
+        got[s_] += list(lg[s_])
+    nxt = np.stack([toks[s_, lens[s_] + 3:lens[s_] + 4] for s_ in range(2)])
+    lg = step(nxt, np.ones((2,), bool))
+    for s_ in range(2):
+        got[s_] += list(lg[s_])
+    tol = 2e-3 if kv_dtype == jnp.float32 else 5e-2
+    for s_ in range(2):
+        n = lens[s_] + 4
+        full, _, _ = transformer.forward(
+            cfg, params, {"tokens": jnp.asarray(toks[s_:s_ + 1, :n])},
+            compute_dtype=jnp.float32)
+        np.testing.assert_allclose(np.stack(got[s_]), np.asarray(full[0]),
+                                   rtol=tol, atol=tol)
+
+
 def test_swa_ring_buffer_bounded_cache():
     cfg = CASES["swa_ring"]
     cache = transformer.cache_init(cfg, 1, 1000, dtype=jnp.float32)

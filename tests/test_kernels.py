@@ -188,6 +188,51 @@ def test_flash_attention_ragged_gqa_through_wrapper():
                                rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.parametrize("offsets,lens,bq,bk", [
+    ((0, 0, 0), (48, 30, 0), 16, 16),        # fresh slots; one empty
+    ((0, 37, 5), (48, 38, 53), 16, 32),      # a riding decode slot at 37
+    ((0, 20, 90), (40, 68, 100), 64, 128),   # blocks clamp to the shapes
+])
+def test_ragged_flash_matches_reference(offsets, lens, bq, bk):
+    """Queries continuing a cache (the serve step's prefill and chunked
+    prefill): per-row query offsets and valid-key counts ride scalar
+    prefetch, with GQA folded through the public wrapper; a row that
+    sees no key outputs 0 in kernel and oracle alike."""
+    b, sq, sk, hq, hkv, dh = 3, 48, 100, 4, 2, 32
+    q = jax.random.normal(KEY, (b, sq, hq, dh), jnp.float32)
+    k = jax.random.normal(jax.random.PRNGKey(1), (b, sk, hkv, dh),
+                          jnp.float32)
+    v = jax.random.normal(jax.random.PRNGKey(2), (b, sk, hkv, dh),
+                          jnp.float32)
+    off = jnp.asarray(offsets, jnp.int32)
+    kv_len = jnp.asarray(lens, jnp.int32)
+    out = mha_attention(q, k, v, block_q=bq, block_k=bk, interpret=True,
+                        q_offset=off, kv_len=kv_len)
+    ref = mha_attention(q, k, v, use_kernel=False, q_offset=off,
+                        kv_len=kv_len)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-3, atol=2e-3)
+    if lens[2] == 0:
+        assert np.abs(np.asarray(out[2])).max() == 0.0
+
+
+def test_flash_gqa_grouping_matches_model_attention():
+    """The wrapper's GQA fold must group heads as the model does (q head j
+    reads KV head j // g, `layers.attention_core`): the flash kernel then
+    stands in for the model's attention, not a head-permuted variant."""
+    from repro.models.layers import attention_core
+    b, s, hq, hkv, dh = 2, 64, 8, 2, 32
+    q = jax.random.normal(KEY, (b, s, hq, dh), jnp.float32)
+    k = jax.random.normal(jax.random.PRNGKey(1), (b, s, hkv, dh), jnp.float32)
+    v = jax.random.normal(jax.random.PRNGKey(2), (b, s, hkv, dh), jnp.float32)
+    pos = jnp.arange(s, dtype=jnp.int32)
+    model = attention_core(q, k, v, pos, pos, causal=True, window=None,
+                           scale=1.0 / dh ** 0.5)
+    out = mha_attention(q, k, v, block_q=32, block_k=32, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(model),
+                               rtol=2e-3, atol=2e-3)
+
+
 def test_fully_masked_rows_output_zero():
     """Pinned degenerate-row convention: a q row with zero surviving keys
     (reachable at sq > sk with a window) outputs 0 in both the kernel

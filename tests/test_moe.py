@@ -64,7 +64,6 @@ SUBPROCESS_SNIPPET = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import axis_types_kwargs, set_mesh
 from repro.models import moe
 from repro.models.config import ModelConfig
 from repro.parallel import sharding as shd
@@ -73,11 +72,12 @@ cfg = ModelConfig(name="t", family="moe", num_layers=1, d_model=32, d_ff=64,
                   vocab_size=64, num_heads=4, num_kv_heads=2,
                   num_experts=8, top_k=2, moe_d_ff=16, capacity_factor=8.0)
 mesh = jax.sharding.Mesh(np.array(jax.devices()).reshape(2, 4),
-                         ("data", "model"), **axis_types_kwargs(2))
+                         ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = shd.single_pod_rules().with_sizes(mesh)
 p = moe.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
-with set_mesh(mesh), shd.use_rules(rules):
+with jax.set_mesh(mesh), shd.use_rules(rules):
     out, _ = jax.jit(lambda p, x: moe.apply_sharded(p, x, cfg))(p, x)
 ref, _ = moe.apply_grouped(p, x.reshape(-1, 32), cfg)
 err = float(jnp.max(jnp.abs(out - ref.reshape(4, 16, 32))))

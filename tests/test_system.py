@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import cost_model, dse, manycore, tiling
-from repro.launch import mesh as mesh_compat
 from repro.parallel import sharding as shd
 
 
@@ -50,7 +49,8 @@ def test_roofline_terms_and_dominance():
 
 
 def test_sharding_rules_drop_indivisible_dims():
-    mesh = mesh_compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = shd.single_pod_rules().with_sizes(mesh)
     # sizes say model=1 => constraint becomes fully replicated, no error
     with shd.use_rules(rules):
