@@ -136,6 +136,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bkv, g, dh), q.dtype),
+        name="decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
@@ -294,6 +295,7 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
         fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
+        name="paged_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),

@@ -134,6 +134,7 @@ def quantized_decode_attention(q: jax.Array, kq: jax.Array, ks: jax.Array,
         fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bkv, g, dh), jnp.float32),
+        name="quantized_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),
@@ -276,6 +277,7 @@ def paged_quantized_gqa_decode_attention(
         fn,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), jnp.float32),
+        name="paged_quantized_decode_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=hardware.TPU_V5E.usable_vmem()),

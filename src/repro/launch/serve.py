@@ -53,6 +53,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 import repro.configs as configs
 from repro.core import hardware
@@ -72,6 +73,10 @@ from repro.runtime.lifecycle import (Lifecycle, Request, State, TERMINAL)
 # success and ordinary failure so the crash-smoke CI job can assert the
 # process really died mid-serve before it attempts `serve --resume`.
 CRASH_EXIT = 17
+
+# The `jax.monitoring` event of one program being lowered (compiled, or
+# loaded from the persistent cache): `serve_loop` counts them.
+COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
 class Server:
@@ -144,35 +149,53 @@ class Server:
         Returns True iff the slot's first-token logits were finite (the
         per-slot guard); may raise `faults.PrefillInterrupt` in chaos mode
         *after* the slot reset — the interrupted slot is left zeroed, so a
-        caller can simply release it and requeue the request."""
-        prompt = np.asarray(prompt, np.int32)
-        if self.cfg.sliding_window:
-            # The ring buffer keeps at most `window` keys; feeding more in
-            # one masked scatter would alias ring rows. A fresh slot only
-            # ever attends the last `window` prompt tokens anyway.
-            prompt = prompt[-self.cfg.sliding_window:]
-        self.cache = transformer.cache_reset_slot(self.cache, slot,
-                                                  paged=self.paged)
-        if self.allocator is not None:
-            # Drop any pages a previous occupant left behind (idempotent),
-            # then cover the prompt before the forward — the masked scatter
-            # needs physical rows to land in.  `ensure` consumes the
-            # scheduler's admission reservation as the pages land.
-            self.allocator.free_slot(slot, rid=int(self.slot_req[slot]))
-            self.allocator.ensure(slot, prompt.size, rid=req_id)
-            self._sync_pages()
-        if self.injector is not None:
-            self.injector.prefill_hook(slot, req_id)   # may raise
-        toks = jnp.zeros((self.batch, prompt.size),
-                         jnp.int32).at[slot].set(prompt)
-        active = jnp.zeros((self.batch,), jnp.bool_).at[slot].set(True)
-        nxt, ok, self.cache = self.serve_step(self.params, self.cache, toks,
-                                              active)
-        self.last_tok = self.last_tok.at[slot, 0].set(int(nxt[slot, 0]))
-        self.slot_len[slot] = 0
-        self.slot_target[slot] = gen_len
-        self.slot_req[slot] = req_id
-        return bool(np.asarray(ok)[slot])
+        caller can simply release it and requeue the request.
+
+        Traced as ``serve.prefill`` with the parts ``serve.prefill.prep``
+        (slot reset, pages, inputs), ``.launch`` (the jitted step's
+        asynchronous dispatch), ``.sync`` (the host reads of the first
+        token and the guard, which wait for the device) and ``.post``
+        (slot bookkeeping); `decode_step` and `admit_chunk` are split
+        the same way."""
+        with TraceAnnotation("serve.prefill", rid=req_id, slot=slot):
+            with TraceAnnotation("serve.prefill.prep"):
+                prompt = np.asarray(prompt, np.int32)
+                if self.cfg.sliding_window:
+                    # The ring buffer keeps at most `window` keys; feeding
+                    # more in one masked scatter would alias ring rows. A
+                    # fresh slot only ever attends the last `window`
+                    # prompt tokens anyway.
+                    prompt = prompt[-self.cfg.sliding_window:]
+                self.cache = transformer.cache_reset_slot(self.cache, slot,
+                                                          paged=self.paged)
+                if self.allocator is not None:
+                    # Drop any pages a previous occupant left behind
+                    # (idempotent), then cover the prompt before the
+                    # forward — the masked scatter needs physical rows to
+                    # land in.  `ensure` consumes the scheduler's admission
+                    # reservation as the pages land.
+                    self.allocator.free_slot(slot,
+                                             rid=int(self.slot_req[slot]))
+                    self.allocator.ensure(slot, prompt.size, rid=req_id)
+                    self._sync_pages()
+                if self.injector is not None:
+                    self.injector.prefill_hook(slot, req_id)   # may raise
+                toks = jnp.zeros((self.batch, prompt.size),
+                                 jnp.int32).at[slot].set(prompt)
+                active = jnp.zeros((self.batch,),
+                                   jnp.bool_).at[slot].set(True)
+            with TraceAnnotation("serve.prefill.launch"):
+                nxt, ok, self.cache = self.serve_step(self.params, self.cache,
+                                                      toks, active)
+            with TraceAnnotation("serve.prefill.sync"):
+                first = int(nxt[slot, 0])
+                ok = bool(np.asarray(ok)[slot])
+            with TraceAnnotation("serve.prefill.post"):
+                self.last_tok = self.last_tok.at[slot, 0].set(first)
+                self.slot_len[slot] = 0
+                self.slot_target[slot] = gen_len
+                self.slot_req[slot] = req_id
+            return ok
 
     def can_chunk(self) -> bool:
         """Chunked prefill needs the (B, S) active-mask machinery, which
@@ -198,54 +221,63 @@ class Server:
         Returns ``(ok_admit, nxt, rode, done, bad)``: per-admitted-slot
         finite-logits verdicts, the token array, the riding slots, and
         the riding slots that finished / went non-finite this step
-        (mirroring `decode_step`'s contract for exactly those slots)."""
-        width = max(int(np.asarray(p).size) for _, _, p, _ in admits)
-        rode = [s for s in range(self.batch) if self.slot_req[s] >= 0]
-        for slot, rid, prompt, _ in admits:
-            self.cache = transformer.cache_reset_slot(self.cache, slot,
-                                                      paged=self.paged)
-            if self.allocator is not None:
-                self.allocator.free_slot(slot, rid=int(self.slot_req[slot]))
-                self.allocator.ensure(slot, np.asarray(prompt).size, rid=rid)
-        if self.allocator is not None:
-            depths = np.asarray(self.cache["lengths"])
-            for s in rode:                     # riding slots grow one token
-                self.allocator.ensure(s, int(depths[s]) + 1,
-                                      rid=int(self.slot_req[s]))
-            self._sync_pages()
-        tokens = np.zeros((self.batch, width), np.int32)
-        act = np.zeros((self.batch, width), bool)
-        last = np.asarray(self.last_tok)
-        for s in rode:
-            tokens[s, 0] = int(last[s, 0])
-            act[s, 0] = True
-        for slot, _, prompt, _ in admits:
-            p = np.asarray(prompt, np.int32)
-            tokens[slot, :p.size] = p
-            act[slot, :p.size] = True
-        nxt, ok, self.cache = self.serve_step(self.params, self.cache,
-                                              jnp.asarray(tokens),
-                                              jnp.asarray(act),
-                                              jnp.asarray(self.poison))
-        self.poison[:] = False
-        ok = np.asarray(ok)
-        nxt_np = np.asarray(nxt)
-        ok_admit = {}
-        new_last = last.copy()
-        for slot, rid, _, gen_len in admits:
-            new_last[slot, 0] = int(nxt_np[slot, 0])
-            self.slot_len[slot] = 0
-            self.slot_target[slot] = gen_len
-            self.slot_req[slot] = rid
-            ok_admit[slot] = bool(ok[slot])
-        adv = [s for s in rode if ok[s]]
-        for s in adv:
-            new_last[s, 0] = int(nxt_np[s, 0])
-            self.slot_len[s] += 1
-        self.last_tok = jnp.asarray(new_last)
-        done = [s for s in adv if self.slot_len[s] >= self.slot_target[s]]
-        bad = [s for s in rode if not ok[s]]
-        return ok_admit, nxt, rode, done, bad
+        (mirroring `decode_step`'s contract for exactly those slots).
+        Traced as ``serve.chunk`` with the four parts of `prefill`."""
+        with TraceAnnotation("serve.chunk", step=step, admitted=len(admits)):
+            with TraceAnnotation("serve.chunk.prep"):
+                width = max(int(np.asarray(p).size) for _, _, p, _ in admits)
+                rode = [s for s in range(self.batch) if self.slot_req[s] >= 0]
+                for slot, rid, prompt, _ in admits:
+                    self.cache = transformer.cache_reset_slot(
+                        self.cache, slot, paged=self.paged)
+                    if self.allocator is not None:
+                        self.allocator.free_slot(
+                            slot, rid=int(self.slot_req[slot]))
+                        self.allocator.ensure(slot, np.asarray(prompt).size,
+                                              rid=rid)
+                if self.allocator is not None:
+                    depths = np.asarray(self.cache["lengths"])
+                    for s in rode:             # riding slots grow one token
+                        self.allocator.ensure(s, int(depths[s]) + 1,
+                                              rid=int(self.slot_req[s]))
+                    self._sync_pages()
+                tokens = np.zeros((self.batch, width), np.int32)
+                act = np.zeros((self.batch, width), bool)
+                last = np.asarray(self.last_tok)
+                for s in rode:
+                    tokens[s, 0] = int(last[s, 0])
+                    act[s, 0] = True
+                for slot, _, prompt, _ in admits:
+                    p = np.asarray(prompt, np.int32)
+                    tokens[slot, :p.size] = p
+                    act[slot, :p.size] = True
+                tokens, act = jnp.asarray(tokens), jnp.asarray(act)
+                poison = jnp.asarray(self.poison)
+            with TraceAnnotation("serve.chunk.launch"):
+                nxt, ok, self.cache = self.serve_step(self.params, self.cache,
+                                                      tokens, act, poison)
+                self.poison[:] = False
+            with TraceAnnotation("serve.chunk.sync"):
+                ok = np.asarray(ok)
+                nxt_np = np.asarray(nxt)
+            with TraceAnnotation("serve.chunk.post"):
+                ok_admit = {}
+                new_last = last.copy()
+                for slot, rid, _, gen_len in admits:
+                    new_last[slot, 0] = int(nxt_np[slot, 0])
+                    self.slot_len[slot] = 0
+                    self.slot_target[slot] = gen_len
+                    self.slot_req[slot] = rid
+                    ok_admit[slot] = bool(ok[slot])
+                adv = [s for s in rode if ok[s]]
+                for s in adv:
+                    new_last[s, 0] = int(nxt_np[s, 0])
+                    self.slot_len[s] += 1
+                self.last_tok = jnp.asarray(new_last)
+                done = [s for s in adv
+                        if self.slot_len[s] >= self.slot_target[s]]
+                bad = [s for s in rode if not ok[s]]
+            return ok_admit, nxt, rode, done, bad
 
     def restore_slot(self, slot: int, rid: int, prompt, tokens,
                      gen_len: int) -> None:
@@ -368,41 +400,48 @@ class Server:
         logits (per-slot guard) — their token is discarded, they did not
         advance, and the caller must quarantine them.  ``use_ref=True``
         runs the jnp-reference step (kernel-dispatch degradation path).
-        May raise `faults.KernelDispatchFault` in chaos mode."""
-        if self.injector is not None and not use_ref:
-            self.injector.apply_decode_faults(self, step)   # may raise
-        if self.allocator is not None:
-            # Decode-boundary crossing: every occupied slot writes one
-            # token this step — grow its page table to cover depth + 1
-            # *before* the forward so the scatter has a physical row.
-            # With reservation-priced admission this never OOMs; an
-            # overcommitted pool raises PageOOM and the serve loop turns
-            # it into an eviction (backpressure), not a crash.
-            depths = np.asarray(self.cache["lengths"])
-            grew = False
-            for slot in range(self.batch):
-                if self.slot_req[slot] >= 0:
-                    grew |= self.allocator.ensure(
-                        slot, int(depths[slot]) + 1,
-                        rid=int(self.slot_req[slot]))
-            if grew:
-                self._sync_pages()
-        active = jnp.asarray(self.slot_req >= 0)
-        poison = jnp.asarray(self.poison)
-        step_fn = self._ref_step() if use_ref else self.serve_step
-        nxt, ok, self.cache = step_fn(self.params, self.cache,
-                                      self.last_tok, active, poison)
-        self.poison[:] = False
-        ok = np.asarray(ok)
-        adv = (self.slot_req >= 0) & ok
-        self.last_tok = jnp.where(jnp.asarray(adv)[:, None], nxt,
-                                  self.last_tok)
-        self.slot_len[adv] += 1
-        done = [s for s in range(self.batch)
-                if adv[s] and self.slot_len[s] >= self.slot_target[s]]
-        bad = [s for s in range(self.batch)
-               if self.slot_req[s] >= 0 and not ok[s]]
-        return nxt, done, bad
+        May raise `faults.KernelDispatchFault` in chaos mode.  Traced as
+        ``serve.decode`` with the four parts of `prefill`."""
+        with TraceAnnotation("serve.decode", step=step):
+            with TraceAnnotation("serve.decode.prep"):
+                if self.injector is not None and not use_ref:
+                    self.injector.apply_decode_faults(self, step)  # may raise
+                if self.allocator is not None:
+                    # Decode-boundary crossing: every occupied slot writes
+                    # one token this step — grow its page table to cover
+                    # depth + 1 *before* the forward so the scatter has a
+                    # physical row.  With reservation-priced admission this
+                    # never OOMs; an overcommitted pool raises PageOOM and
+                    # the serve loop turns it into an eviction
+                    # (backpressure), not a crash.
+                    depths = np.asarray(self.cache["lengths"])
+                    grew = False
+                    for slot in range(self.batch):
+                        if self.slot_req[slot] >= 0:
+                            grew |= self.allocator.ensure(
+                                slot, int(depths[slot]) + 1,
+                                rid=int(self.slot_req[slot]))
+                    if grew:
+                        self._sync_pages()
+                active = jnp.asarray(self.slot_req >= 0)
+                poison = jnp.asarray(self.poison)
+                step_fn = self._ref_step() if use_ref else self.serve_step
+            with TraceAnnotation("serve.decode.launch"):
+                nxt, ok, self.cache = step_fn(self.params, self.cache,
+                                              self.last_tok, active, poison)
+                self.poison[:] = False
+            with TraceAnnotation("serve.decode.sync"):
+                ok = np.asarray(ok)
+            with TraceAnnotation("serve.decode.post"):
+                adv = (self.slot_req >= 0) & ok
+                self.last_tok = jnp.where(jnp.asarray(adv)[:, None], nxt,
+                                          self.last_tok)
+                self.slot_len[adv] += 1
+                done = [s for s in range(self.batch)
+                        if adv[s] and self.slot_len[s] >= self.slot_target[s]]
+                bad = [s for s in range(self.batch)
+                       if self.slot_req[s] >= 0 and not ok[s]]
+            return nxt, done, bad
 
     def _ref_step(self):
         """The jnp-reference serve step, traced with the fused decode
@@ -446,7 +485,14 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
     fills a slot, decodes, jumps the virtual clock to the next
     retry-backoff eligibility or arrival, or raises with the lifecycle
     table — no silent no-progress spinning.  Returns loop-level stats for
-    the summary (generated token count, steps, kernel fallbacks).
+    the summary (generated token count, steps, kernel fallbacks, and
+    ``compiles``: programs lowered while the loop ran).
+
+    Each pass is a ``serve.iter`` profiler span holding ``serve.admit``
+    (one per admitted request, with its queue wait), ``serve.emit``,
+    ``serve.retire``, ``serve.deadlines``, ``serve.wait`` and
+    ``serve.snapshot`` spans and the `Server` calls' own; with no trace
+    running they cost well under a microsecond each.
 
     ``source`` (optional, see `runtime.loadgen`) is pumped every
     iteration: it submits trace requests whose arrival time has been
@@ -472,12 +518,15 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
     step = start_step
     last_snap = start_step
     generated = 0
+    emitted = 0              # tokens emitted, first tokens included
+    occupied = 0             # slots taking part in this iteration's step
     kernel_fallbacks = 0
     max_concurrent = 0
     kv_pages_peak = 0
     kv_peak = None           # allocator utilization snapshot at the peak
     kv_ooms = 0
     chunked_prefills = 0
+    compiles = 0
     t_start = time.monotonic()
 
     def note_kv() -> None:
@@ -492,10 +541,11 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
     def emit(req, tok: int) -> None:
         """Write-ahead token emission: journal first, then append (the
         externally visible effect)."""
-        nonlocal first_new_token_s
+        nonlocal first_new_token_s, emitted
         if journal is not None:
             journal.token(req.rid, len(req.tokens), tok, step)
         req.tokens.append(tok)
+        emitted += 1
         if first_new_token_s is None:
             first_new_token_s = time.monotonic() - t_start
 
@@ -519,7 +569,22 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
         return (lc.open_count() > 0
                 or (source is not None and not source.exhausted()))
 
-    while pending():
+    def admit_span(req, slot: int):
+        """``serve.admit``: one admitted request; ``wait_ms`` is its time
+        in the queue, from submission to the start of its admission."""
+        return TraceAnnotation("serve.admit", rid=req.rid, slot=slot,
+                               wait_ms=(lc.clock() - req.submit_t) * 1e3)
+
+    def count_compile(event: str, duration: float, **kw) -> None:
+        nonlocal compiles
+        if event == COMPILE_EVENT:
+            compiles += 1
+
+    def iterate() -> bool:
+        """One pass of the loop (a ``serve.iter`` span); False once
+        nothing is pending after the deadline sweep."""
+        nonlocal step, generated, kernel_fallbacks, max_concurrent, \
+            kv_ooms, chunked_prefills, occupied
         if tick is not None:
             tick(step)
         if source is not None:
@@ -530,7 +595,8 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
                 f"lifecycle table:\n{lc.table()}")
         # -- periodic snapshot (crash-tolerance checkpoint) -----------------
         if snapshots is not None and snapshots.due(step, last_snap):
-            take_snapshot()
+            with TraceAnnotation("serve.snapshot"):
+                take_snapshot()
         # -- fill idle slots from the admission queue -----------------------
         admits = []
         for slot in range(server.batch):
@@ -548,85 +614,93 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
             # burst of arrivals costs one step instead of stalling decode
             # behind per-request prefills.
             for slot, req in admits:
-                lc.transition(req, State.PREFILLING, step)
+                with admit_span(req, slot):
+                    lc.transition(req, State.PREFILLING, step)
             ok_admit, c_nxt, c_rode, c_done, c_bad = server.admit_chunk(
                 [(slot, req.rid, req.prompt, req.gen_len)
                  for slot, req in admits], step)
             chunked_prefills += 1
-            for slot, req in admits:
-                if not ok_admit[slot]:
-                    server.release_slot(slot)
-                    lc.evict(req, step, reason="nan_prefill")
-                    continue
-                emit(req, int(server.last_tok[slot, 0]))
-                lc.record_first_token(req)
-                lc.transition(req, State.DECODING, step)
+            with TraceAnnotation("serve.emit"):
+                for slot, req in admits:
+                    if not ok_admit[slot]:
+                        server.release_slot(slot)
+                        lc.evict(req, step, reason="nan_prefill")
+                        continue
+                    emit(req, int(server.last_tok[slot, 0]))
+                    lc.record_first_token(req)
+                    lc.transition(req, State.DECODING, step)
             chunk = (c_nxt, c_rode, c_done, c_bad)
         else:
             for slot, req in admits:
-                lc.transition(req, State.PREFILLING, step)
-                try:
-                    ok = server.prefill(slot, req.rid, req.prompt,
-                                        req.gen_len)
-                except faults.PrefillInterrupt:
-                    # the slot was reset before the interrupt: release it
-                    server.release_slot(slot)
-                    if server.allocator is not None:
-                        server.allocator.release_reservation(req.rid)
-                    lc.evict(req, step, reason="prefill_interrupt")
-                    continue
-                except paging.PageOOM:
-                    # Defensive: admission reservations normally cover the
-                    # prompt; an overcommitted pool requeues the request
-                    # (backpressure), never crashes the server.
-                    kv_ooms += 1
-                    server.release_slot(slot)
-                    if server.allocator is not None:
-                        server.allocator.release_reservation(req.rid)
-                    lc.evict(req, step, reason="kv_oom")
-                    continue
-                if not ok:
-                    server.release_slot(slot)
-                    lc.evict(req, step, reason="nan_prefill")
-                    continue
-                emit(req, int(server.last_tok[slot, 0]))
-                lc.record_first_token(req)
-                lc.transition(req, State.DECODING, step)
-        max_concurrent = max(max_concurrent,
-                             int((server.slot_req >= 0).sum()))
+                with admit_span(req, slot):
+                    lc.transition(req, State.PREFILLING, step)
+                    try:
+                        ok = server.prefill(slot, req.rid, req.prompt,
+                                            req.gen_len)
+                    except faults.PrefillInterrupt:
+                        # the slot was reset before the interrupt: release
+                        server.release_slot(slot)
+                        if server.allocator is not None:
+                            server.allocator.release_reservation(req.rid)
+                        lc.evict(req, step, reason="prefill_interrupt")
+                        continue
+                    except paging.PageOOM:
+                        # Defensive: admission reservations normally cover
+                        # the prompt; an overcommitted pool requeues the
+                        # request (backpressure), never crashes the server.
+                        kv_ooms += 1
+                        server.release_slot(slot)
+                        if server.allocator is not None:
+                            server.allocator.release_reservation(req.rid)
+                        lc.evict(req, step, reason="kv_oom")
+                        continue
+                    if not ok:
+                        server.release_slot(slot)
+                        lc.evict(req, step, reason="nan_prefill")
+                        continue
+                    emit(req, int(server.last_tok[slot, 0]))
+                    lc.record_first_token(req)
+                    lc.transition(req, State.DECODING, step)
+        occupied = int((server.slot_req >= 0).sum())
+        max_concurrent = max(max_concurrent, occupied)
         note_kv()
         # -- deadline sweep -------------------------------------------------
-        for req in lc.check_deadlines(step):
-            tslot = np.nonzero(server.slot_req == req.rid)[0]
-            if tslot.size:
-                server.release_slot(int(tslot[0]))
+        with TraceAnnotation("serve.deadlines"):
+            for req in lc.check_deadlines(step):
+                tslot = np.nonzero(server.slot_req == req.rid)[0]
+                if tslot.size:
+                    server.release_slot(int(tslot[0]))
         if not pending():
-            break
+            return False
         # -- progress check -------------------------------------------------
-        occupied = server.slot_req >= 0
-        if not occupied.any():
-            jumps = [s for s in (
-                lc.next_eligible_step(),
-                source.next_arrival_step(lc, step)
-                if source is not None else None) if s is not None]
-            if not jumps:
-                raise RuntimeError(
-                    "serve loop stalled: no occupied slots, empty queue, "
-                    f"but {lc.open_count()} request(s) not in a terminal "
-                    f"state — a request leaked.  Lifecycle table:\n"
-                    f"{lc.table()}")
-            # every queued request is in retry backoff (or the next trace
-            # arrival is in the future): jump the virtual clock to the
-            # earliest eligibility instead of spinning
-            step = max(step + 1, min(jumps))
-            continue
+        live = server.slot_req >= 0
+        if not live.any():
+            with TraceAnnotation("serve.wait"):
+                jumps = [s for s in (
+                    lc.next_eligible_step(),
+                    source.next_arrival_step(lc, step)
+                    if source is not None else None) if s is not None]
+                if not jumps:
+                    raise RuntimeError(
+                        "serve loop stalled: no occupied slots, empty "
+                        f"queue, but {lc.open_count()} request(s) not in a "
+                        f"terminal state — a request leaked.  Lifecycle "
+                        f"table:\n{lc.table()}")
+                # every queued request is in retry backoff (or the next
+                # trace arrival is in the future): jump the virtual clock
+                # to the earliest eligibility instead of spinning
+                step = max(step + 1, min(jumps))
+            return True
         # -- one ragged decode step (or the chunk's riding results) ---------
         if chunk is not None:
             # the chunked forward already advanced every riding decode
             # slot; newly admitted slots take their first decode step on
-            # the next iteration
+            # the next iteration.  A rider the deadline sweep has released
+            # since is neither emitted to nor retired.
             nxt, rode, done, bad = chunk
-            advanced = [s for s in rode if s not in bad]
+            done = [s for s in done if live[s]]
+            bad = [s for s in bad if live[s]]
+            advanced = [s for s in rode if live[s] and s not in bad]
         else:
             t0 = time.monotonic()
             try:
@@ -654,27 +728,45 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
                 server.release_slot(victim)
                 lc.evict(vreq, step, reason="kv_oom")
                 step += 1
-                continue
+                return True
             if watchdog is not None:
                 watchdog.observe(step, time.monotonic() - t0)
             advanced = [s for s in range(server.batch)
                         if server.slot_req[s] >= 0 and s not in bad]
         note_kv()                    # decode growth can also set the peak
         # tokens for every slot that advanced this step
-        for slot in advanced:
-            emit(lc.requests[int(server.slot_req[slot])], int(nxt[slot, 0]))
-            generated += 1
-        for slot in bad:
-            # quarantine exactly the poisoned slot: reset + requeue; the
-            # neighbours' rows were never touched (per-slot masked writes)
-            req = lc.requests[int(server.slot_req[slot])]
-            server.release_slot(slot)
-            lc.evict(req, step, reason="nan_decode")
-        for slot in done:
-            req = lc.requests[int(server.slot_req[slot])]
-            lc.transition(req, State.COMPLETED, step)
-            server.release_slot(slot)
+        with TraceAnnotation("serve.emit"):
+            for slot in advanced:
+                emit(lc.requests[int(server.slot_req[slot])],
+                     int(nxt[slot, 0]))
+                generated += 1
+        with TraceAnnotation("serve.retire"):
+            for slot in bad:
+                # quarantine exactly the poisoned slot: reset + requeue;
+                # the neighbours' rows were never touched (per-slot masked
+                # writes)
+                req = lc.requests[int(server.slot_req[slot])]
+                server.release_slot(slot)
+                lc.evict(req, step, reason="nan_decode")
+            for slot in done:
+                req = lc.requests[int(server.slot_req[slot])]
+                lc.transition(req, State.COMPLETED, step)
+                server.release_slot(slot)
         step += 1
+        return True
+
+    # Compiles are counted only while the loop runs: the listener is
+    # process-wide.
+    jax.monitoring.register_event_duration_secs_listener(count_compile)
+    try:
+        more = True
+        while more and pending():
+            with TraceAnnotation("serve.iter", step=step) as it:
+                before = emitted
+                more = iterate()
+                it.set_metadata(occupied=occupied, tokens=emitted - before)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count_compile)
     if not lc.conserved():
         raise RuntimeError(
             "request conservation violated after drain: "
@@ -688,6 +780,7 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
             "kv_peak": kv_peak,
             "kv_ooms": kv_ooms,
             "chunked_prefills": chunked_prefills,
+            "compiles": compiles,
             "snapshots_saved": 0 if snapshots is None else snapshots.saved}
 
 
@@ -1006,6 +1099,9 @@ def _summary(server, lc, stats, wall, *, batch, batch_source,
         "snapshots_saved": stats.get("snapshots_saved", 0),
         "max_concurrent": stats.get("max_concurrent", 0),
         "chunked_prefills": stats.get("chunked_prefills", 0),
+        # programs lowered while serving: each new prompt or chunk width
+        # compiles inside the loop
+        "compiles": stats["compiles"],
         "ttft_ms": lc.ttft_percentiles(),
         "per_token_ms": lc.per_token_percentiles(),
         "request_outcomes": lc.outcome_trace(),

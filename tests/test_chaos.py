@@ -79,8 +79,10 @@ def test_same_fault_seed_identical_outcome_trace():
         lc, stats, injector = _run(cfg, 2, _requests(cfg, spec),
                                    plan=faults.FaultPlan.smoke(3))
         # first_new_token_s is wall-clock (volatile by contract, like
-        # loadgen's VOLATILE_FIELDS) — everything else must replay exactly
-        stats = {k: v for k, v in stats.items() if k != "first_new_token_s"}
+        # loadgen's VOLATILE_FIELDS) and compiles depends on what the
+        # process compiled before — everything else must replay exactly
+        stats = {k: v for k, v in stats.items()
+                 if k not in ("first_new_token_s", "compiles")}
         runs.append((lc.outcome_trace(), injector.record(), _tokens(lc),
                      stats))
     assert runs[0] == runs[1]

@@ -11,6 +11,7 @@ one process at a time may load the TPU compiler's library.
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -39,10 +40,14 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", enabled)
 
 
-def _compile(one_chip, fn, *shapes):
+def _compile(one_chip, fn, *shapes, kernel=None):
+    """Compile for the described chip; with ``kernel``, the custom call
+    must carry that name (what the profiler's `XLA Ops` line shows)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    if kernel is not None:
+        assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call\(", text)
     return text
 
 
@@ -70,7 +75,8 @@ def test_contiguous_decode_compiles(one_chip, dtype):
     b, L = 8, 4096
     kv = ((b, L, HKV, DH), dtype)
     _compile(one_chip, lambda q, k, v, n: gqa_decode_attention(
-        q, k, v, length=n), ((b, HQ, DH), dtype), kv, kv, ((b,), jnp.int32))
+        q, k, v, length=n), ((b, HQ, DH), dtype), kv, kv, ((b,), jnp.int32),
+        kernel="decode_attention")
 
 
 def test_contiguous_int8_decode_compiles(one_chip):
@@ -80,7 +86,8 @@ def test_contiguous_int8_decode_compiles(one_chip):
     kq, ks = ((b, L, HKV, DH), jnp.int8), ((b, L, HKV), jnp.float32)
     _compile(one_chip, lambda q, a, sa, v, sv, n:
              quantized_gqa_decode_attention(q, a, sa, v, sv, length=n),
-             ((b, HQ, DH), jnp.bfloat16), kq, ks, kq, ks, ((b,), jnp.int32))
+             ((b, HQ, DH), jnp.bfloat16), kq, ks, kq, ks, ((b,), jnp.int32),
+             kernel="quantized_decode_attention")
 
 
 @pytest.mark.parametrize("quantized", [False, True])
@@ -98,11 +105,13 @@ def test_paged_decode_compiles(one_chip, quantized):
         _compile(one_chip, lambda q, a, sa, v, sv, t, n:
                  paged_quantized_gqa_decode_attention(q, a, sa, v, sv, t,
                                                       length=n),
-                 q, kq, ks, kq, ks, *tables)
+                 q, kq, ks, kq, ks, *tables,
+                 kernel="paged_quantized_decode_attention")
     else:
         kv = (pool, jnp.float32)
         _compile(one_chip, lambda q, k, v, t, n: paged_gqa_decode_attention(
-            q, k, v, t, length=n), q, kv, kv, *tables)
+            q, k, v, t, length=n), q, kv, kv, *tables,
+            kernel="paged_decode_attention")
 
 
 @pytest.mark.parametrize("rank", [0, 1, 2])
