@@ -18,13 +18,13 @@ rope_theta 1,000,000, rms_norm_eps 1e-6, tie_word_embeddings false.
 * assumed: nothing beyond the published config; weights are random from
   a seed (`Server` uses PRNGKey(0)).
 
-Why 2 layers, from bytes (the serve path keeps params in f32 and does not
-donate the cache, so old and new cache both count):
+Why 2 layers, from bytes (the serve path does not donate the cache, so
+old and new cache both count), first for params stored in f32:
 
 * one layer: 330.3 M params (attention 62.9 M + SwiGLU 267.4 M), 1.32 GB
   in f32;
 * embedding + head: 2 * 151,936 * 5120 = 1.556 B params, 6.22 GB in f32;
-* inside the step the weights are cast to bf16 (the head alone ~1.6 GB);
+* inside the step the weights were cast to bf16 (the head alone ~1.6 GB);
   `transformer.init` holds every layer twice while it stacks them, so it
   peaks at 6.22 + 2 * 1.32 * N GB before the first step runs.
 
@@ -41,6 +41,11 @@ and a 536-row f32 cache (arguments + outputs + temporaries):
 N = 2 leaves ~6 GB of HBM for the float32 reference of the on-chip
 correctness check and for the KV cache and batch that benchmark cells
 fill; N = 3 would leave ~2 GB at init.
+
+Stored in bf16, as `Server` stores them, the params take 4.43 GB at
+N = 2, and the same compile reads 4.47 + 0.04 + 1.12 =
+5.63 GB (prefill), 4.50 GB (decode, no temporaries: the step makes no
+copy of a weight).
 """
 
 import dataclasses

@@ -118,8 +118,17 @@ class Server:
                                                     kv_dtype=self.kv_dtype,
                                                     slot_lengths=slot_lengths)
                             if autotune_kernels else [])
+        # Stored in the dtype the step computes in (norm scales and the
+        # other leaves it reads in f32 stay f32), so no step re-reads wide
+        # weights or writes narrowed copies of them.
         self.params = transformer.init(cfg, jax.random.PRNGKey(0),
-                                       dtype=jnp.float32)
+                                       dtype=transformer.COMPUTE_DTYPE)
+        # Stored parameter bytes by dtype name (the serve summary's
+        # `param_bytes`).
+        self.param_bytes = {}
+        for leaf in jax.tree.leaves(self.params):
+            dt = leaf.dtype.name
+            self.param_bytes[dt] = self.param_bytes.get(dt, 0) + leaf.nbytes
         self.serve_step = jax.jit(
             steps.make_guarded_serve_step(cfg, paged=paged))
         # The degradation step: same math forced onto the jnp reference
@@ -252,7 +261,9 @@ class Server:
                     tokens[slot, :p.size] = p
                     act[slot, :p.size] = True
                 tokens, act = jnp.asarray(tokens), jnp.asarray(act)
-                poison = jnp.asarray(self.poison)
+                # a copy: the transfer may still read the host buffer when
+                # the arm is reset after the launch
+                poison = jnp.asarray(self.poison.copy())
             with TraceAnnotation("serve.chunk.launch"):
                 nxt, ok, self.cache = self.serve_step(self.params, self.cache,
                                                       tokens, act, poison)
@@ -424,7 +435,7 @@ class Server:
                     if grew:
                         self._sync_pages()
                 active = jnp.asarray(self.slot_req >= 0)
-                poison = jnp.asarray(self.poison)
+                poison = jnp.asarray(self.poison.copy())    # see admit_chunk
                 step_fn = self._ref_step() if use_ref else self.serve_step
             with TraceAnnotation("serve.decode.launch"):
                 nxt, ok, self.cache = step_fn(self.params, self.cache,
@@ -1107,6 +1118,7 @@ def _summary(server, lc, stats, wall, *, batch, batch_source,
         "request_outcomes": lc.outcome_trace(),
         "watchdog": watchdog.summary(),
         "kv_dtype": server.kv_dtype.name,
+        "param_bytes": server.param_bytes,
         "kernel_plan": [p.record() for p in server.kernel_plan],
     }
     if scheduler is not None:

@@ -31,8 +31,9 @@ def _dense_init(key, shape, scale=DEFAULT_INIT_SCALE, dtype=jnp.float32):
 # Norms
 # ---------------------------------------------------------------------------
 
-def rmsnorm_init(d: int, dtype=jnp.float32) -> Params:
-    return {"scale": jnp.ones((d,), dtype=dtype)}
+def rmsnorm_init(d: int) -> Params:
+    # f32 at any storage dtype: `rmsnorm` reads the scale in f32.
+    return {"scale": jnp.ones((d,), jnp.float32)}
 
 
 def rmsnorm(params: Params, x: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -82,8 +83,8 @@ def attention_init(key, cfg, dtype=jnp.float32) -> Params:
         p["bk"] = jnp.zeros((cfg.kv_dim,), dtype)
         p["bv"] = jnp.zeros((cfg.kv_dim,), dtype)
     if cfg.qk_norm:
-        p["q_norm"] = rmsnorm_init(cfg.head_dim, dtype)
-        p["k_norm"] = rmsnorm_init(cfg.head_dim, dtype)
+        p["q_norm"] = rmsnorm_init(cfg.head_dim)
+        p["k_norm"] = rmsnorm_init(cfg.head_dim)
     return p
 
 

@@ -36,7 +36,7 @@ def rwkv_time_init(key, cfg, dtype=jnp.float32) -> Params:
         "decay_a": layers._dense_init(ks[5], (d, l), dtype=jnp.float32),
         "decay_b": layers._dense_init(ks[6], (l, d), dtype=jnp.float32),
         "bonus_u": jnp.zeros((h, cfg.rwkv_head_dim), jnp.float32),
-        "ln_x": layers.rmsnorm_init(d, jnp.float32),
+        "ln_x": layers.rmsnorm_init(d),
     }
 
 

@@ -28,8 +28,9 @@ def mamba_init(key, cfg, dtype=jnp.float32) -> Params:
         "conv_w": layers._dense_init(ks[1], (cfg.ssm_conv, d_in), scale=0.1, dtype=dtype),
         "conv_b": jnp.zeros((d_in,), dtype),
         "x_proj": layers._dense_init(ks[2], (d_in, r + 2 * n), dtype=dtype),
-        "dt_proj": layers._dense_init(ks[3], (r, d_in), scale=r**-0.5, dtype=dtype),
-        "dt_bias": jnp.full((d_in,), -4.6, dtype),     # softplus^-1(0.01)
+        # dt_proj and dt_bias are read in f32 (the delta path), so kept f32
+        "dt_proj": layers._dense_init(ks[3], (r, d_in), scale=r**-0.5),
+        "dt_bias": jnp.full((d_in,), -4.6, jnp.float32),  # softplus^-1(0.01)
         "A_log": jnp.log(a),                           # kept f32
         "D": jnp.ones((d_in,), jnp.float32),
         "out_proj": layers._dense_init(ks[4], (d_in, d), dtype=dtype),

@@ -25,6 +25,10 @@ from repro.parallel.sharding import constrain
 
 Params = dict
 
+# The dtype `forward` computes in: activations, and every matmul weight it
+# casts to the activations' dtype.
+COMPUTE_DTYPE = jnp.bfloat16
+
 
 # ---------------------------------------------------------------------------
 # Per-layer init / apply / cache-init, keyed by the cfg-static layer kind
@@ -227,6 +231,13 @@ def cache_specs(cfg: ModelConfig, paged=None, kv_dtype=None):
 # ---------------------------------------------------------------------------
 
 def init(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
+    """Parameters stored in ``dtype`` where `forward` casts them to the
+    activations' dtype (projections, MLP and expert weights, embedding and
+    head tables, QKV biases, conv weights, shift mixes), and in f32 where
+    it reads them in f32 (norm scales, the router, the SSM/RWKV decay and
+    delta leaves).  So a tree stored at ``dtype=COMPUTE_DTYPE`` gives
+    bitwise the results of the f32 tree it was rounded from, and the step
+    casts no weight."""
     ke, kl, kh, kf = jax.random.split(key, 4)
     params: Params = {"embed": layers.embedding_init(ke, cfg.vocab_size,
                                                      cfg.d_model, dtype)}
@@ -381,7 +392,7 @@ def _embed_inputs(cfg: ModelConfig, params: Params, inputs: dict) -> jax.Array:
 
 
 def forward(cfg: ModelConfig, params: Params, inputs: dict,
-            cache: Params | None = None, compute_dtype=jnp.bfloat16,
+            cache: Params | None = None, compute_dtype=COMPUTE_DTYPE,
             return_hidden: bool = False, last_only: bool = False,
             active: jax.Array | None = None, paged=None):
     """Returns (logits-or-hidden, new_cache, aux_loss).
