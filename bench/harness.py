@@ -10,7 +10,9 @@ around the calls into the program; it changes nothing inside them.
 Everything that belongs to one configuration, mix or per-layer metric is
 a file of its own, found by the name `BENCHMARK.json` gives it:
 ``bench/configs/<config>.json``, ``bench/mixes/<traffic>.json`` and
-``bench/metrics/<metric>.py``.
+``bench/metrics/<metric>.py``; and the model's program config, weights and
+plain reference are ``bench/models/<model_type>.py``, found by the
+configuration's own ``model_type`` (`bench.models`).
 """
 
 from __future__ import annotations
@@ -27,6 +29,8 @@ import sys
 import time
 
 import numpy as np
+
+from bench import models
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -56,25 +60,10 @@ def load_cell(workload: str, root=ROOT) -> dict:
                        f"known: {sorted(cells)}")
     cell = cells[workload]
     config = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    return {"bench": bench, "cell": cell,
-            "spec": load_json(pathlib.Path(root) / config["file"]),
+    spec = load_json(pathlib.Path(root) / config["file"])
+    models.for_spec(spec)                 # a model the benchmark can serve
+    return {"bench": bench, "cell": cell, "spec": spec,
             "mix": load_json(BENCH / "mixes" / f"{cell['traffic']}.json")}
-
-
-def model_config(spec: dict):
-    """The program's `ModelConfig` for a configuration file."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=spec["name"], family="dense",
-        num_layers=spec["num_hidden_layers"], d_model=spec["hidden_size"],
-        d_ff=spec["intermediate_size"], vocab_size=spec["vocab_size"],
-        num_heads=spec["num_attention_heads"],
-        num_kv_heads=spec["num_key_value_heads"], head_dim=spec["head_dim"],
-        qk_norm=bool(spec.get("qk_norm")), qkv_bias=bool(spec.get("qkv_bias")),
-        rope_theta=float(spec["rope_theta"]),
-        norm_eps=float(spec["rms_norm_eps"]),
-        tie_embeddings=bool(spec["tie_word_embeddings"]),
-        remat=spec.get("remat", "none"))
 
 
 def per_layer_metrics(bench: dict, workload: str) -> list[dict]:
@@ -437,10 +426,11 @@ def sample_for_check(lc, source, seed: int, want_tokens: int) -> list:
 
 
 def check(spec, seed, picked, controls=()) -> dict:
-    from bench import reference, weights
+    from bench import weights
+    model = models.for_spec(spec)
     w = weights.make(spec, seed)
-    rows = [reference.served_gap(w, spec, r.prompt, r.tokens,
-                                 controls=controls) for r in picked]
+    rows = [model.served_gap(w, spec, r.prompt, r.tokens, controls=controls)
+            for r in picked]
     del w
     out = {"logit_gap": max((r["gap"] for r in rows), default=math.inf),
            "tokens": sum(r["tokens"] for r in rows),
@@ -512,7 +502,7 @@ def build(cell: dict, seed: int) -> Rig:
     from repro.parallel import sharding as shd
 
     spec, mix = cell["spec"], cell["mix"]
-    cfg = model_config(spec)
+    cfg = models.for_spec(spec).program_config(spec)
     buckets = [int(b) for b in mix["prompt_buckets"]]
     compiles = Compiles()
     mesh = make_host_mesh(data=1, model=1)
@@ -627,8 +617,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     """One run; returns the result object the command prints.
 
     ``cell`` (a `load_cell` result) and ``mutate`` (called with the built
-    server before the window) are for tests; ``controls`` (precisions of
-    `bench.reference`) adds each lower-precision control's gap to the
+    server before the window) are for tests; ``controls`` (the model
+    module's lower-precision controls) adds each control's gap to the
     check."""
     t_start = time.perf_counter() if t_start is None else t_start
     cell = cell or load_cell(workload, root)

@@ -1,7 +1,22 @@
-"""The plain float32 reference of the served models.
+"""Qwen3 (``model_type`` ``qwen3``): the dense GQA decoder of the published
+Qwen2.5 / Qwen3 equations, as the benchmark serves it and as its plain
+reference computes it.
 
-A decoder of the published Qwen2.5 / Qwen3 equations in straightforward
-``jax.numpy``, written from the published description and importing
+Configuration keys are the published config's.  ``qkv_bias`` (biases on
+the Q, K and V projections, Qwen2.5) and ``qk_norm`` (per-head RMSNorm of
+Q and K before RoPE, Qwen3) are the two switches that tell the two apart;
+`bench.models.qwen2` is this module.
+
+`program_config`, `PROGRAM_PATHS` and `weight_shapes` map the
+configuration onto the program's dense decoder and its parameter tree.
+Weights are named as in the published checkpoints (``q_proj``,
+``gate_proj``, ...) and stacked over layers; matmul weights are
+``(in, out)``.  Norm scales draw ``1 + N(0, 0.2)`` and QKV biases
+``N(0, 0.5)`` (`bench.weights.SCALE`), so that a path that skipped a norm's
+scale or a bias would move the logits well past rounding.
+
+The plain float32 reference (`logits`, `served_gap`) is written in
+straightforward ``jax.numpy`` from the published description and imports
 nothing of the program: token embedding; per layer, RMSNorm, Q/K/V
 projections (with their biases where the configuration has ``qkv_bias``),
 per-head RMSNorm of Q and K before RoPE where it has ``qk_norm``, rotary
@@ -32,6 +47,80 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+def program_config(spec: dict):
+    """The program's `ModelConfig` for a configuration file."""
+    from repro.models.config import ModelConfig
+    return ModelConfig(
+        name=spec["name"], family="dense",
+        num_layers=spec["num_hidden_layers"], d_model=spec["hidden_size"],
+        d_ff=spec["intermediate_size"], vocab_size=spec["vocab_size"],
+        num_heads=spec["num_attention_heads"],
+        num_kv_heads=spec["num_key_value_heads"], head_dim=spec["head_dim"],
+        qk_norm=bool(spec.get("qk_norm")), qkv_bias=bool(spec.get("qkv_bias")),
+        rope_theta=float(spec["rope_theta"]),
+        norm_eps=float(spec["rms_norm_eps"]),
+        tie_embeddings=bool(spec["tie_word_embeddings"]),
+        remat=spec.get("remat", "none"))
+
+
+# The program's parameter paths (``launch/serve.py``'s ``Server.params``)
+# and the published names they hold.
+PROGRAM_PATHS = {
+    "embed/table": "embed_tokens",
+    "head/table": "lm_head",
+    "final_norm/scale": "norm",
+    "blocks/ln1/scale": "input_layernorm",
+    "blocks/ln2/scale": "post_attention_layernorm",
+    "blocks/mixer/wq": "q_proj",
+    "blocks/mixer/wk": "k_proj",
+    "blocks/mixer/wv": "v_proj",
+    "blocks/mixer/wo": "o_proj",
+    "blocks/mixer/bq": "q_proj_bias",
+    "blocks/mixer/bk": "k_proj_bias",
+    "blocks/mixer/bv": "v_proj_bias",
+    "blocks/mixer/q_norm/scale": "q_norm",
+    "blocks/mixer/k_norm/scale": "k_norm",
+    "blocks/mlp/w_gate": "gate_proj",
+    "blocks/mlp/w_up": "up_proj",
+    "blocks/mlp/w_down": "down_proj",
+}
+
+
+def weight_shapes(spec: dict) -> dict:
+    """Published name -> (shape, kind).  Matmul weights are (in, out)."""
+    d, f, v = spec["hidden_size"], spec["intermediate_size"], \
+        spec["vocab_size"]
+    l = spec["num_hidden_layers"]
+    hq, hkv = spec["num_attention_heads"], spec["num_key_value_heads"]
+    dh = spec["head_dim"]
+    out = {
+        "embed_tokens": ((v, d), "dense"),
+        "lm_head": ((v, d), "dense"),
+        "norm": ((d,), "norm"),
+        "input_layernorm": ((l, d), "norm"),
+        "post_attention_layernorm": ((l, d), "norm"),
+        "q_proj": ((l, d, hq * dh), "dense"),
+        "k_proj": ((l, d, hkv * dh), "dense"),
+        "v_proj": ((l, d, hkv * dh), "dense"),
+        "o_proj": ((l, hq * dh, d), "dense"),
+        "gate_proj": ((l, d, f), "dense"),
+        "up_proj": ((l, d, f), "dense"),
+        "down_proj": ((l, f, d), "dense"),
+    }
+    if spec.get("qkv_bias"):
+        out.update({"q_proj_bias": ((l, hq * dh), "bias"),
+                    "k_proj_bias": ((l, hkv * dh), "bias"),
+                    "v_proj_bias": ((l, hkv * dh), "bias")})
+    if spec.get("qk_norm"):
+        out.update({"q_norm": ((l, dh), "norm"), "k_norm": ((l, dh), "norm")})
+    return out
+
+
+# ---------------------------------------------------------------------------
+# The plain reference
+# ---------------------------------------------------------------------------
 
 HI = jax.lax.Precision.HIGHEST
 PAD = 512          # sequence padding quantum (bounds the number of compiles)

@@ -6,30 +6,33 @@ import json
 import pathlib
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
 
 from bench import harness
+from bench.models import qwen3
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SECONDS = 3.0
 
 
-def tiny_cell():
+def tiny_cell(**over):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    spec = json.loads((DATA / "tiny.config.json").read_text())
     return {"bench": {**bench, "workloads": [{"name": "tiny.chat"}],
                       "end_to_end": [{**m, "workloads": ["tiny.chat"]}
                                      for m in bench["end_to_end"]]},
             "cell": {"name": "tiny.chat", "chips": 1},
-            "spec": json.loads((DATA / "tiny.config.json").read_text()),
+            "spec": {**spec, **over},
             "mix": json.loads((DATA / "tiny.mix.json").read_text())}
 
 
-def run(mutate=None, seed=1, controls=()):
+def run(mutate=None, seed=1, controls=(), cell=None):
     return harness.run_cell("tiny.chat", seed, SECONDS, False,
-                            require_tpu=False, cell=tiny_cell(),
+                            require_tpu=False, cell=cell or tiny_cell(),
                             mutate=mutate, controls=controls)
 
 
@@ -84,6 +87,39 @@ def test_a_broken_timed_path_is_not_correct(fault, seed):
     assert res["correct"] is False
     chk = res["checks"]["logit_gap"]
     assert chk["value"] > chk["limit"] and chk["correct"] is False
+
+
+def test_a_model_module_added_as_new_code_is_the_one_a_run_uses(
+        monkeypatch):
+    """A model module registered under its ``model_type`` alone (here the
+    Qwen3 module's functions, each recording its calls) gives the run its
+    program config, its weight table and parameter paths, and its check."""
+    calls = []
+
+    def recorded(fn):
+        def call(*a, **kw):
+            calls.append(fn.__name__)
+            return fn(*a, **kw)
+        return call
+
+    class Paths(dict):
+        def get(self, key, default=None):
+            calls.append("PROGRAM_PATHS")
+            return super().get(key, default)
+
+    stub = types.ModuleType("bench.models.stub_decoder")
+    stub.program_config = recorded(qwen3.program_config)
+    stub.weight_shapes = recorded(qwen3.weight_shapes)
+    stub.served_gap = recorded(qwen3.served_gap)
+    stub.PROGRAM_PATHS = Paths(qwen3.PROGRAM_PATHS)
+    monkeypatch.setitem(sys.modules, stub.__name__, stub)
+    res = run(cell=tiny_cell(model_type="stub_decoder"))
+    assert res["correct"] is True
+    # build: the config, then the weights as the program's tree; check:
+    # the weights by name, then the reference over each sampled request.
+    assert calls.index("program_config") < calls.index("PROGRAM_PATHS")
+    assert calls.count("weight_shapes") == 2
+    assert "served_gap" in calls and calls[-1] == "served_gap"
 
 
 def test_no_chip_no_result(tmp_path):

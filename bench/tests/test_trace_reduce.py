@@ -4,6 +4,7 @@ time, and the breakdown; on hand-made intervals and on a small trace
 recorded on a TPU v5e."""
 
 import pathlib
+import random
 import re
 
 import pytest
@@ -27,6 +28,20 @@ def test_union_merges_overlaps():
     assert tr.union([(3, 4), (1, 2), (1.5, 2.5), (4, 5)]) == [(1, 2.5),
                                                                 (3, 5)]
     assert tr.covered([(1, 2.5), (3, 5)], 2, 4) == pytest.approx(1.5)
+
+
+def test_covered_is_the_sum_over_every_interval():
+    """Visiting only the overlapping intervals gives the sum over all of
+    them, bit for bit, wherever the range starts and ends."""
+    rng = random.Random(7)
+    starts = sorted(rng.uniform(0, 100) for _ in range(400))
+    merged = tr.union((a, a + rng.uniform(0, 0.5)) for a in starts)
+    edges = [a for a, _ in merged[::37]] + [b for _, b in merged[::41]]
+    for _ in range(300):
+        lo = rng.choice(edges + [rng.uniform(-5, 105)])
+        hi = rng.choice(edges + [rng.uniform(lo, 110)])
+        every = sum(max(0.0, min(b, hi) - max(a, lo)) for a, b in merged)
+        assert tr.covered(merged, lo, hi) == every
 
 
 def test_busy_counts_the_window_only():

@@ -19,6 +19,7 @@ trace's clock.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -49,8 +50,13 @@ def union(intervals) -> list[tuple[float, float]]:
 
 
 def covered(merged, lo: float, hi: float) -> float:
-    """Length of ``[lo, hi]`` that the merged intervals cover."""
-    return sum(max(0.0, min(b, hi) - max(a, lo)) for a, b in merged)
+    """Length of ``[lo, hi]`` that the merged intervals cover.  Only the
+    intervals that overlap it are summed (a window holds hundreds of
+    thousands, and every decode span asks); the others add nothing."""
+    i = bisect.bisect_right(merged, lo, key=lambda ab: ab[1])
+    j = bisect.bisect_left(merged, hi, lo=i, key=lambda ab: ab[0])
+    return sum((max(0.0, min(b, hi) - max(a, lo)) for a, b in merged[i:j]),
+               0.0)
 
 
 @dataclasses.dataclass
