@@ -129,7 +129,12 @@ class Server:
         for leaf in jax.tree.leaves(self.params):
             dt = leaf.dtype.name
             self.param_bytes[dt] = self.param_bytes.get(dt, 0) + leaf.nbytes
-        self.serve_step = jax.jit(
+        # One jitted step for every call.  `serve_step` is called as the
+        # decode step, ``(params, cache, tokens, active, poison)``, the
+        # signature a wrapper of the decode step sees; prefill and the
+        # chunk call the same function as `_admit_step`, with the device
+        # `last_tok` and the admitted slots as well.
+        self.serve_step = self._admit_step = jax.jit(
             steps.make_guarded_serve_step(cfg, paged=paged))
         # The degradation step: same math forced onto the jnp reference
         # path ($REPRO_DECODE_KERNEL=off at trace time) — built lazily on
@@ -143,9 +148,18 @@ class Server:
         self.slot_req = -np.ones(batch, np.int32)      # request id
         self.last_tok = jnp.zeros((batch, 1), jnp.int32)
         self.poison = np.zeros(batch, bool)            # chaos logits-NaN arm
+        # Blocking device-to-host reads made by `prefill`, `admit_chunk`
+        # and `decode_step`: one per call (`_read`), plus paged mode's read
+        # of the cache depths before a decode step or a chunk.
+        self.host_reads = 0
+
+    def _read(self, x) -> np.ndarray:
+        """One blocking device-to-host read, counted in `host_reads`."""
+        self.host_reads += 1
+        return np.asarray(x)
 
     def prefill(self, slot: int, req_id: int, prompt: np.ndarray,
-                gen_len: int) -> bool:
+                gen_len: int) -> tuple[bool, int]:
         """Masked batched prefill of one slot: the whole prompt in a single
         forward whose ``active`` mask is the slot's one-hot, so ONLY this
         slot's cache rows are written and only its per-slot length advances
@@ -155,17 +169,19 @@ class Server:
         stale KV/state rows are zeroed first — a refilled slot must be
         indistinguishable from a fresh one.
 
-        Returns True iff the slot's first-token logits were finite (the
-        per-slot guard); may raise `faults.PrefillInterrupt` in chaos mode
-        *after* the slot reset — the interrupted slot is left zeroed, so a
-        caller can simply release it and requeue the request.
+        Returns ``(ok, first)``: ``ok`` is True iff the slot's first-token
+        logits were finite (the per-slot guard), ``first`` the first token
+        as a host int, which the step also wrote into ``last_tok``; may
+        raise `faults.PrefillInterrupt` in chaos mode *after* the slot
+        reset — the interrupted slot is left zeroed, so a caller can simply
+        release it and requeue the request.
 
         Traced as ``serve.prefill`` with the parts ``serve.prefill.prep``
         (slot reset, pages, inputs), ``.launch`` (the jitted step's
-        asynchronous dispatch), ``.sync`` (the host reads of the first
-        token and the guard, which wait for the device) and ``.post``
-        (slot bookkeeping); `decode_step` and `admit_chunk` are split
-        the same way."""
+        asynchronous dispatch), ``.sync`` (the call's single host read:
+        the tokens and the guard in one array, which waits for the device;
+        its ``reads`` stat counts it) and ``.post`` (slot bookkeeping);
+        `decode_step` and `admit_chunk` are split the same way."""
         with TraceAnnotation("serve.prefill", rid=req_id, slot=slot):
             with TraceAnnotation("serve.prefill.prep"):
                 prompt = np.asarray(prompt, np.int32)
@@ -189,22 +205,24 @@ class Server:
                     self._sync_pages()
                 if self.injector is not None:
                     self.injector.prefill_hook(slot, req_id)   # may raise
-                toks = jnp.zeros((self.batch, prompt.size),
-                                 jnp.int32).at[slot].set(prompt)
-                active = jnp.zeros((self.batch,),
-                                   jnp.bool_).at[slot].set(True)
+                toks = np.zeros((self.batch, prompt.size), np.int32)
+                toks[slot] = prompt
+                active = np.zeros(self.batch, bool)
+                active[slot] = True
+                toks, active = jnp.asarray(toks), jnp.asarray(active)
             with TraceAnnotation("serve.prefill.launch"):
-                nxt, ok, self.cache = self.serve_step(self.params, self.cache,
-                                                      toks, active)
-            with TraceAnnotation("serve.prefill.sync"):
-                first = int(nxt[slot, 0])
-                ok = bool(np.asarray(ok)[slot])
+                # the slot is admitted: it takes its first token in
+                # `last_tok` whatever the guard says
+                out, self.last_tok, self.cache = self._admit_step(
+                    self.params, self.cache, toks, active, None,
+                    self.last_tok, active)
+            with TraceAnnotation("serve.prefill.sync", reads=1):
+                out = self._read(out)
             with TraceAnnotation("serve.prefill.post"):
-                self.last_tok = self.last_tok.at[slot, 0].set(first)
                 self.slot_len[slot] = 0
                 self.slot_target[slot] = gen_len
                 self.slot_req[slot] = req_id
-            return ok
+            return bool(out[slot, 1]), int(out[slot, 0])
 
     def can_chunk(self) -> bool:
         """Chunked prefill needs the (B, S) active-mask machinery, which
@@ -228,10 +246,13 @@ class Server:
         riding slots their next one from the same forward.
 
         Returns ``(ok_admit, nxt, rode, done, bad)``: per-admitted-slot
-        finite-logits verdicts, the token array, the riding slots, and
-        the riding slots that finished / went non-finite this step
-        (mirroring `decode_step`'s contract for exactly those slots).
-        Traced as ``serve.chunk`` with the four parts of `prefill`."""
+        finite-logits verdicts, the (B, 1) host token array (an admitted
+        slot's first token, a riding slot's next one), the riding slots,
+        and the riding slots that finished / went non-finite this step
+        (mirroring `decode_step`'s contract for exactly those slots).  The
+        step itself puts each rider's ``last_tok`` at column 0 and
+        advances ``last_tok``.  Traced as ``serve.chunk`` with the four
+        parts of `prefill`; ``.sync`` is the call's single host read."""
         with TraceAnnotation("serve.chunk", step=step, admitted=len(admits)):
             with TraceAnnotation("serve.chunk.prep"):
                 width = max(int(np.asarray(p).size) for _, _, p, _ in admits)
@@ -245,46 +266,45 @@ class Server:
                         self.allocator.ensure(slot, np.asarray(prompt).size,
                                               rid=rid)
                 if self.allocator is not None:
-                    depths = np.asarray(self.cache["lengths"])
+                    depths = self._read(self.cache["lengths"])
                     for s in rode:             # riding slots grow one token
                         self.allocator.ensure(s, int(depths[s]) + 1,
                                               rid=int(self.slot_req[s]))
                     self._sync_pages()
+                # a riding row's column 0 stays 0 here: the step reads
+                # the slot's `last_tok` into it
                 tokens = np.zeros((self.batch, width), np.int32)
                 act = np.zeros((self.batch, width), bool)
-                last = np.asarray(self.last_tok)
-                for s in rode:
-                    tokens[s, 0] = int(last[s, 0])
-                    act[s, 0] = True
+                admit = np.zeros(self.batch, bool)
+                act[rode, 0] = True
                 for slot, _, prompt, _ in admits:
                     p = np.asarray(prompt, np.int32)
                     tokens[slot, :p.size] = p
                     act[slot, :p.size] = True
+                    admit[slot] = True
                 tokens, act = jnp.asarray(tokens), jnp.asarray(act)
+                admit = jnp.asarray(admit)
                 # a copy: the transfer may still read the host buffer when
                 # the arm is reset after the launch
                 poison = jnp.asarray(self.poison.copy())
             with TraceAnnotation("serve.chunk.launch"):
-                nxt, ok, self.cache = self.serve_step(self.params, self.cache,
-                                                      tokens, act, poison)
+                out, self.last_tok, self.cache = self._admit_step(
+                    self.params, self.cache, tokens, act, poison,
+                    self.last_tok, admit)
                 self.poison[:] = False
-            with TraceAnnotation("serve.chunk.sync"):
-                ok = np.asarray(ok)
-                nxt_np = np.asarray(nxt)
+            with TraceAnnotation("serve.chunk.sync", reads=1):
+                out = self._read(out)
             with TraceAnnotation("serve.chunk.post"):
+                nxt, ok = out[:, :1], out[:, 1] != 0
                 ok_admit = {}
-                new_last = last.copy()
                 for slot, rid, _, gen_len in admits:
-                    new_last[slot, 0] = int(nxt_np[slot, 0])
                     self.slot_len[slot] = 0
                     self.slot_target[slot] = gen_len
                     self.slot_req[slot] = rid
                     ok_admit[slot] = bool(ok[slot])
                 adv = [s for s in rode if ok[s]]
                 for s in adv:
-                    new_last[s, 0] = int(nxt_np[s, 0])
                     self.slot_len[s] += 1
-                self.last_tok = jnp.asarray(new_last)
                 done = [s for s in adv
                         if self.slot_len[s] >= self.slot_target[s]]
                 bad = [s for s in rode if not ok[s]]
@@ -315,10 +335,9 @@ class Server:
         # schedule is keyed on live prefill ordinals, not recovery work.
         inj, self.injector = self.injector, None
         try:
-            ok = self.prefill(slot, rid, prefix, gen_len)
+            ok, predicted = self.prefill(slot, rid, prefix, gen_len)
         finally:
             self.injector = inj
-        predicted = int(self.last_tok[slot, 0])
         if not ok or predicted != tokens[-1]:
             raise RuntimeError(
                 f"deterministic recovery violated for request {rid}: "
@@ -406,13 +425,17 @@ class Server:
         decode kernel's scalar-prefetch vector); idle slots neither write
         nor advance.
 
-        Returns ``(next_tokens, done_slots, bad_slots)``: ``done`` slots
-        hit their stop length this step; ``bad`` slots produced non-finite
-        logits (per-slot guard) — their token is discarded, they did not
-        advance, and the caller must quarantine them.  ``use_ref=True``
-        runs the jnp-reference step (kernel-dispatch degradation path).
-        May raise `faults.KernelDispatchFault` in chaos mode.  Traced as
-        ``serve.decode`` with the four parts of `prefill`."""
+        Returns ``(next_tokens, done_slots, bad_slots)``: ``next_tokens``
+        is a (B, 1) host array; ``done`` slots hit their stop length this
+        step; ``bad`` slots produced non-finite logits (per-slot guard) —
+        their token is discarded, they did not advance (the step advances
+        ``last_tok`` only where the guard holds), and the caller must
+        quarantine them.  ``use_ref=True`` runs the jnp-reference step
+        (kernel-dispatch degradation path).  May raise
+        `faults.KernelDispatchFault` in chaos mode.  Traced as
+        ``serve.decode`` with the four parts of `prefill`; ``.sync`` is
+        the call's single host read, and ``.post`` host bookkeeping
+        only."""
         with TraceAnnotation("serve.decode", step=step):
             with TraceAnnotation("serve.decode.prep"):
                 if self.injector is not None and not use_ref:
@@ -425,7 +448,7 @@ class Server:
                     # never OOMs; an overcommitted pool raises PageOOM and
                     # the serve loop turns it into an eviction
                     # (backpressure), not a crash.
-                    depths = np.asarray(self.cache["lengths"])
+                    depths = self._read(self.cache["lengths"])
                     grew = False
                     for slot in range(self.batch):
                         if self.slot_req[slot] >= 0:
@@ -438,15 +461,14 @@ class Server:
                 poison = jnp.asarray(self.poison.copy())    # see admit_chunk
                 step_fn = self._ref_step() if use_ref else self.serve_step
             with TraceAnnotation("serve.decode.launch"):
-                nxt, ok, self.cache = step_fn(self.params, self.cache,
-                                              self.last_tok, active, poison)
+                out, self.last_tok, self.cache = step_fn(
+                    self.params, self.cache, self.last_tok, active, poison)
                 self.poison[:] = False
-            with TraceAnnotation("serve.decode.sync"):
-                ok = np.asarray(ok)
+            with TraceAnnotation("serve.decode.sync", reads=1):
+                out = self._read(out)
             with TraceAnnotation("serve.decode.post"):
+                nxt, ok = out[:, :1], out[:, 1] != 0
                 adv = (self.slot_req >= 0) & ok
-                self.last_tok = jnp.where(jnp.asarray(adv)[:, None], nxt,
-                                          self.last_tok)
                 self.slot_len[adv] += 1
                 done = [s for s in range(self.batch)
                         if adv[s] and self.slot_len[s] >= self.slot_target[s]]
@@ -503,7 +525,9 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
     (one per admitted request, with its queue wait), ``serve.emit``,
     ``serve.retire``, ``serve.deadlines``, ``serve.wait`` and
     ``serve.snapshot`` spans and the `Server` calls' own; with no trace
-    running they cost well under a microsecond each.
+    running they cost well under a microsecond each.  Each `Server` call
+    reads the device once, in its ``.sync``, and returns host tokens, so
+    ``serve.emit`` indexes numpy arrays and reads nothing from the device.
 
     ``source`` (optional, see `runtime.loadgen`) is pumped every
     iteration: it submits trace requests whose arrival time has been
@@ -637,7 +661,7 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
                         server.release_slot(slot)
                         lc.evict(req, step, reason="nan_prefill")
                         continue
-                    emit(req, int(server.last_tok[slot, 0]))
+                    emit(req, int(c_nxt[slot, 0]))
                     lc.record_first_token(req)
                     lc.transition(req, State.DECODING, step)
             chunk = (c_nxt, c_rode, c_done, c_bad)
@@ -646,8 +670,8 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
                 with admit_span(req, slot):
                     lc.transition(req, State.PREFILLING, step)
                     try:
-                        ok = server.prefill(slot, req.rid, req.prompt,
-                                            req.gen_len)
+                        ok, first = server.prefill(slot, req.rid,
+                                                   req.prompt, req.gen_len)
                     except faults.PrefillInterrupt:
                         # the slot was reset before the interrupt: release
                         server.release_slot(slot)
@@ -669,7 +693,7 @@ def serve_loop(server: Server, lc: Lifecycle, *, watchdog=None,
                         server.release_slot(slot)
                         lc.evict(req, step, reason="nan_prefill")
                         continue
-                    emit(req, int(server.last_tok[slot, 0]))
+                    emit(req, first)
                     lc.record_first_token(req)
                     lc.transition(req, State.DECODING, step)
         occupied = int((server.slot_req >= 0).sum())
@@ -1113,6 +1137,9 @@ def _summary(server, lc, stats, wall, *, batch, batch_source,
         # programs lowered while serving: each new prompt or chunk width
         # compiles inside the loop
         "compiles": stats["compiles"],
+        # blocking device-to-host reads: one per prefill, chunk and decode
+        # step (paged mode adds one per decode step and chunk)
+        "host_reads": server.host_reads,
         "ttft_ms": lc.ttft_percentiles(),
         "per_token_ms": lc.per_token_percentiles(),
         "request_outcomes": lc.outcome_trace(),

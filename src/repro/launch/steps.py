@@ -106,19 +106,38 @@ def make_serve_step(cfg: ModelConfig, paged=None):
 
 def make_guarded_serve_step(cfg: ModelConfig, paged=None):
     """`make_serve_step` plus the per-slot NaN/Inf logits guard (and the
-    chaos logits-poison hook) — the step the fault-tolerant server runs.
+    chaos logits-poison hook) and the advance of each slot's last token —
+    the step the fault-tolerant server runs.
 
-    Returns ``(next_token, ok, cache)`` where ``ok`` is a (B,) bool: True
-    iff the slot's final-position logits are entirely finite.  A False
-    slot's token is garbage and its cache may be poisoned — the serve loop
-    quarantines exactly that slot (reset + requeue) while its neighbours,
-    whose rows are untouched (per-slot masked writes), keep decoding
-    bitwise-identically to a fault-free run.  ``poison`` ((B,) bool,
-    chaos-injection only) overwrites a slot's logits with NaN *after* the
-    forward, so the guard is exercised without corrupting model state.
+    ``serve_step(params, cache, tokens, active, poison, last_tok, admit)``
+    returns ``(out, last_tok, cache)``.  ``out`` is one (B, 2) int32 array,
+    all the host needs from the step: column 0 the next token, column 1
+    ``ok``, 1 iff the slot's final-position logits are entirely finite.  A
+    slot with ``ok`` 0 has a garbage token and maybe a poisoned cache — the
+    serve loop quarantines exactly that slot (reset + requeue) while its
+    neighbours, whose rows are untouched (per-slot masked writes), keep
+    decoding bitwise-identically to a fault-free run.  ``poison`` ((B,)
+    bool, chaos-injection only) overwrites a slot's logits with NaN *after*
+    the forward, so the guard is exercised without corrupting model state.
+
+    The returned ``last_tok`` ((B, 1) int32) is the input one with each
+    advancing slot's token replaced by its next one: a slot that takes
+    part in the step (``active``) advances where ``ok`` holds, and an
+    ``admit``ted slot ((B,) bool, prefill) takes its first token whatever
+    ``ok`` says.  ``last_tok`` defaults to ``tokens``, which is what a
+    decode step feeds.  With a (B, S) ``active`` and ``admit`` (the chunked
+    prefill) a riding slot — active, not admitted — reads its own
+    ``last_tok`` at column 0 in place of whatever ``tokens`` holds there.
     """
 
-    def serve_step(params, cache, tokens, active=None, poison=None):
+    def serve_step(params, cache, tokens, active=None, poison=None,
+                   last_tok=None, admit=None):
+        if last_tok is None:
+            last_tok = tokens[:, -1:]
+        if admit is not None and active.ndim == 2:
+            ride = active[:, 0] & ~admit
+            tokens = tokens.at[:, 0].set(
+                jnp.where(ride, last_tok[:, 0], tokens[:, 0]))
         logits, new_cache, _ = transformer.forward(
             cfg, params, {"tokens": tokens}, cache=cache, active=active,
             paged=paged)
@@ -127,6 +146,13 @@ def make_guarded_serve_step(cfg: ModelConfig, paged=None):
             last = jnp.where(poison[:, None], jnp.nan, last)
         ok = jnp.all(jnp.isfinite(last), axis=-1)
         nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        return nxt[:, None], ok, new_cache
+        take = ok
+        if active is not None:
+            take &= active if active.ndim == 1 else jnp.any(active, axis=1)
+        if admit is not None:
+            take |= admit
+        new_last = jnp.where(take[:, None], nxt[:, None], last_tok)
+        out = jnp.stack([nxt, ok.astype(jnp.int32)], axis=1)
+        return out, new_last, new_cache
 
     return serve_step

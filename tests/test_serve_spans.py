@@ -121,6 +121,32 @@ def test_every_span_appears_inside_its_parent(traced):
                    and s.t1 <= p.t1 for p in spans), s
 
 
+def test_each_call_splits_in_order_and_reads_once(traced):
+    """The names and nesting the benchmark's readers depend on: each
+    `Server` call holds its four parts once each, in order, its ``.sync``
+    is the call's single host read (``reads=1``), and an iteration that
+    decodes emits the step's tokens in one ``serve.emit`` after it."""
+    spans = traced["spans"]
+    parts = ("prep", "launch", "sync", "post")
+    assert set(harness.reader("decode_host_ms.serve").PARTS) <= {
+        f"serve.decode.{p}" for p in parts}
+    assert program_spans.CALLS == ("serve.prefill", "serve.chunk",
+                                   "serve.decode")
+    for call in program_spans.CALLS:
+        calls = [s for s in spans if s.kind == call]
+        assert calls, call
+        kinds = tuple(f"{call}.{p}" for p in parts)
+        for c in calls:
+            inner = program_spans.inside(c, spans, kinds)
+            assert [s.kind for s in inner] == list(kinds), c
+            assert int(inner[2].stats["reads"]) == 1
+    for it in (s for s in spans if s.kind == "serve.iter"):
+        decode = program_spans.inside(it, spans, ("serve.decode",))
+        if decode:
+            (emit,) = program_spans.inside(it, spans, ("serve.emit",))
+            assert emit.t0 >= decode[0].t1
+
+
 def test_iter_and_admit_stats(traced):
     lc, spans = traced["lc"], traced["spans"]
     iters = [s for s in spans if s.kind == "serve.iter"]
